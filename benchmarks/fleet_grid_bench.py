@@ -54,6 +54,7 @@ from repro.core.power import B200_POWER, GB200_POWER, H100_POWER, H200_POWER
 from repro.core.profiles import (B200_LLAMA70B_FLEET, GB200_LLAMA70B,
                                  H100_LLAMA70B, H200_LLAMA70B)
 from repro.core.workloads import AZURE
+from repro.models.compat import enable_compile_cache
 from repro.serving import prepare_topology, run_fleet_grid
 
 from .fleet_sim_bench import BENCH_JSON, _TableTimer, write_bench_json
@@ -137,27 +138,11 @@ def grid_cells():
     return cells
 
 
-def _enable_compile_cache() -> None:
-    """Persist XLA builds under benchmarks/results/.xla_cache (never
-    committed): the handful of drain programs compile once per machine,
-    so re-measuring the surface after the first run pays only warmed
-    execution.  Best-effort — an old jax without CPU cache support just
-    compiles every run."""
-    try:                                               # pragma: no cover
-        import jax
-        cache = GRID_BENCH_JSON.parent / ".xla_cache"
-        cache.mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(cache))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except Exception:
-        pass
-
-
 def run(n_requests: int = DEFAULT_N_REQUESTS, seed: int = 0,
         engine: str = "jax", width: int = DEFAULT_WIDTH):
     if engine == "jax":
-        _enable_compile_cache()
+        # the handful of drain programs compile once per cache directory
+        enable_compile_cache()
     cells = grid_cells()
     timer = _TableTimer(dict(n_requests=n_requests, seed=seed,
                              engine=engine, width=width))
@@ -229,15 +214,8 @@ def derive(rows) -> str:
 
 
 def harness_run():
-    """benchmarks.run entry point (rows, derived); falls back to a cheap
-    numpy subsample when jax is missing (the numpy-only perf job) so the
-    harness never hard-fails on environment."""
-    try:
-        import jax  # noqa: F401
-        engine = "jax"
-    except ImportError:                                # pragma: no cover
-        return [], "skipped: jax not installed (numpy-only environment)"
-    rows, derived, timings = run(engine=engine)
+    """benchmarks.run entry point (rows, derived)."""
+    rows, derived, timings = run(engine="jax")
     write_bench_json(timings, GRID_BENCH_JSON.with_name(
         "BENCH_fleet_grid_full.json"))
     return rows, derived

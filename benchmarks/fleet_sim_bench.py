@@ -356,6 +356,9 @@ def main(argv=None) -> None:
         from repro.serving import TraceRecorder, to_perfetto
         recorder = TraceRecorder(level="lifecycle")
         FleetSim.default_telemetry = recorder
+    if args.engine == "jax":
+        from repro.models.compat import enable_compile_cache
+        enable_compile_cache()
     rows, derived, timings = run(n_requests=n, slo_requests=n_slo,
                                  seed=args.seed, quick=args.quick,
                                  engine=args.engine)

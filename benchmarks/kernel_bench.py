@@ -25,8 +25,8 @@ def run():
     # decode attention: B=8 sequences, 4K cache, GQA 8/2
     ks = jax.random.split(rng, 4)
     q = jax.random.normal(ks[0], (8, 8, 64))
-    k = jax.random.normal(ks[1], (8, 4096, 2, 64))
-    v = jax.random.normal(ks[2], (8, 4096, 2, 64))
+    k = jax.random.normal(ks[1], (8, 2, 4096, 64))
+    v = jax.random.normal(ks[2], (8, 2, 4096, 64))
     lengths = jnp.full((8,), 4096)
     f_ref = jax.jit(lambda *a: ops.decode_attention(*a, force="ref"))
     rows.append(dict(name="decode_attention_ref_b8_t4096",
@@ -41,16 +41,16 @@ def run():
                      us_per_call=_time(f_fa, q2, k2, k2),
                      derived="flops=%.2e" % (4 * 1024 * 1024 * 8 * 64)))
     # ssm scans
-    xt = jax.random.normal(ks[0], (2, 512, 4, 64))
+    xt = jax.random.normal(ks[0], (2, 4, 512, 64))
     Bm = jax.random.normal(ks[1], (2, 512, 64))
-    lA = -jnp.abs(jax.random.normal(ks[2], (2, 512, 4)))
+    lA = -jnp.abs(jax.random.normal(ks[2], (2, 4, 512)))
     f_ssd = jax.jit(lambda *a: ops.ssd_scan(*a, force="ref"))
     rows.append(dict(name="ssd_scan_ref_2x512",
                      us_per_call=_time(f_ssd, xt, Bm, Bm, lA),
                      derived="state=(4,64,64)"))
-    r = jax.random.normal(ks[0], (2, 256, 4, 64))
+    r = jax.random.normal(ks[0], (2, 4, 256, 64))
     w = jnp.exp(-jnp.exp(-6 + 0.1 * jax.random.normal(ks[1],
-                                                      (2, 256, 4, 64))))
+                                                      (2, 4, 256, 64))))
     u = jnp.ones((4, 64)) * 0.5
     f_wkv = jax.jit(lambda *a: ops.wkv_scan(*a, force="ref"))
     rows.append(dict(name="wkv6_ref_2x256",

@@ -28,7 +28,7 @@ def _suites():
         # only by a deliberate `fleet_sim_bench.py --quick --json ...
         # --time`; see dump_name below)
         "fleet_sim": fleet_sim_bench.harness_run,
-        # Table E sensitivity surface; self-skips on numpy-only hosts
+        # Table E sensitivity surface on the compiled engine
         "fleet_grid": fleet_grid_bench.harness_run,
         # searched vs hand-built TopologySpec fleets (optimize_topology);
         # the committed --quick baseline results/topology_search.json is

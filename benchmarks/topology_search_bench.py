@@ -133,6 +133,9 @@ def main(argv=None) -> None:
     args = ap.parse_args(argv)
     n = 1500 if args.quick else args.slo_requests
     budget = 10 if args.quick else args.budget
+    if args.engine == "jax":
+        from repro.models.compat import enable_compile_cache
+        enable_compile_cache()
     rows, derived = run(slo_requests=n, seed=args.seed, budget=budget,
                         quick=args.quick, engine=args.engine)
     if args.json:

@@ -4,10 +4,12 @@ Decode attention is memory-bound: per iteration every sequence streams its
 whole KV cache (kappa * L bytes) HBM -> VMEM once.  This kernel expresses
 that stream explicitly: grid = (batch, kv_head, kv_blocks) with the KV-block
 dimension innermost/sequential, carrying online-softmax state (m, l, acc) in
-VMEM scratch.  Block shapes are (BLOCK_T, 128)-aligned for the VPU/MXU;
-the G = H/K query heads of a GQA group ride along in one tile so each KV
-block is read exactly once per group (not per head) — the TPU-native
-adaptation of TP-sharded GQA decode (DESIGN.md §3).
+VMEM scratch.  The cache is laid out head-major, (B, K, T, D), so every KV
+block is a (BLOCK_T, D) tile that meets the TPU's (8, 128) tiling; the
+G = H/K query heads of a GQA group ride along in one tile so each KV block
+is read exactly once per group (not per head) — the TPU-native adaptation
+of TP-sharded GQA decode (DESIGN.md §3).  The per-sequence lengths sit in
+SMEM whole, indexed by the batch grid position.
 """
 from __future__ import annotations
 
@@ -20,6 +22,9 @@ from jax.experimental.pallas import tpu as pltpu
 
 DEFAULT_BLOCK_T = 256
 NEG_INF = -1e30
+
+# q @ k^T: contract the head dim of both operands
+_NT = (((1,), (1,)), ((), ()))
 
 
 def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
@@ -34,11 +39,12 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     q = q_ref[0, 0].astype(jnp.float32)              # (G, D)
-    k = k_ref[0, :, 0].astype(jnp.float32)           # (Tb, D)
-    v = v_ref[0, :, 0].astype(jnp.float32)           # (Tb, D)
-    length = len_ref[0]
+    k = k_ref[0, 0].astype(jnp.float32)              # (Tb, D)
+    v = v_ref[0, 0].astype(jnp.float32)              # (Tb, D)
+    length = len_ref[pl.program_id(0)]
 
-    s = jnp.dot(q, k.T) / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
+    s = jax.lax.dot_general(q, k, _NT, preferred_element_type=jnp.float32)
+    s = s / jnp.sqrt(jnp.asarray(q.shape[-1], jnp.float32))
     t_idx = t * block_t + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
     s = jnp.where(t_idx < length, s, NEG_INF)
 
@@ -47,7 +53,8 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
     l_new = l_prev * corr + p.sum(axis=1, keepdims=True)
-    acc_new = acc_prev * corr + jnp.dot(p, v)
+    acc_new = acc_prev * corr + jnp.dot(p, v,
+                                        preferred_element_type=jnp.float32)
 
     m_ref[...], l_ref[...], acc_ref[...] = m_new, l_new, acc_new
 
@@ -61,33 +68,33 @@ def _flash_decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref,
 def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
                  lengths: jax.Array, *, block_t: int = DEFAULT_BLOCK_T,
                  interpret: bool = True) -> jax.Array:
-    """q: (B, H, D); k, v: (B, T, K, D); lengths: (B,) -> (B, H, D).
+    """q: (B, H, D); k, v: (B, K, T, D) head-major; lengths: (B,)
+    -> (B, H, D).
 
-    interpret=True executes the kernel body in Python on CPU (this
-    container); on a real TPU pass interpret=False.
+    interpret=True executes the kernel body in Python on CPU; on a real
+    TPU pass interpret=False (block_t a multiple of 8, or of 16 in bf16).
     """
     B, H, D = q.shape
-    T, K = k.shape[1], k.shape[2]
+    K, T = k.shape[1], k.shape[2]
     G = H // K
     block_t = min(block_t, T)
     n_blocks = -(-T // block_t)
     pad_t = n_blocks * block_t - T
     if pad_t:
-        k = jnp.pad(k, ((0, 0), (0, pad_t), (0, 0), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, pad_t), (0, 0), (0, 0)))
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, pad_t), (0, 0)))
     qh = q.reshape(B, K, G, D)
 
     kernel = functools.partial(_flash_decode_kernel, block_t=block_t,
                                n_blocks=n_blocks)
+    kv_spec = pl.BlockSpec((1, 1, block_t, D), lambda b, h, t: (b, h, t, 0))
     out = pl.pallas_call(
         kernel,
         grid=(B, K, n_blocks),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, h, t: (b,),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, D), lambda b, h, t: (b, h, 0, 0)),
-            pl.BlockSpec((1, block_t, 1, D), lambda b, h, t: (b, t, h, 0)),
-            pl.BlockSpec((1, block_t, 1, D), lambda b, h, t: (b, t, h, 0)),
+            kv_spec, kv_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, G, D), lambda b, h, t: (b, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((B, K, G, D), q.dtype),
@@ -97,5 +104,5 @@ def flash_decode(q: jax.Array, k: jax.Array, v: jax.Array,
             pltpu.VMEM((G, D), jnp.float32),     # acc
         ],
         interpret=interpret,
-    )(lengths, qh, k, v)
+    )(lengths.astype(jnp.int32), qh, k, v)
     return out.reshape(B, H, D)

@@ -20,6 +20,7 @@ import numpy as np
 from repro.configs import get_config
 from repro.core.profiles import H100_LLAMA70B
 from repro.core.workloads import WORKLOADS
+from repro.models.compat import enable_compile_cache
 from repro.models import model as M
 from repro.serving import (ContextRouter, PoolEngine, RouterPolicy,
                            synthetic_requests)
@@ -60,6 +61,7 @@ def main() -> None:
     ap.add_argument("--window-long", type=int, default=192)
     ap.add_argument("--policies", default="homo,two_pool,fleetopt")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch).reduced()
     params = M.init_params(jax.random.PRNGKey(0), cfg)

@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from repro.configs import get_config
 from repro.data import batch_iterator
+from repro.models.compat import enable_compile_cache
 from repro.models.spec import ArchConfig
 from repro.training import AdamW, save_checkpoint, train_loop
 
@@ -57,6 +58,7 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=1e-3)
     ap.add_argument("--ckpt", default=None)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = scaled_config(args.arch, args.preset)
     print(f"config {cfg.name}: {cfg.param_count() / 1e6:.1f}M params, "

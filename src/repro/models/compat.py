@@ -1,74 +1,67 @@
-"""JAX version compatibility shims for the ambient-mesh API.
+"""The one home of JAX APIs whose spelling moves between releases.
 
-The ambient ("abstract") mesh API moved between JAX releases:
-
-  * 0.5.x+ — `jax.sharding.get_abstract_mesh()` / `jax.sharding.set_mesh()`
-    (earlier spelled `use_mesh`), and `jax.make_mesh` grew an `axis_types`
-    kwarg.
-  * 0.4.x — none of those exist; the ambient mesh is the thread-resources
-    physical mesh installed by `with mesh:`.
-
-Everything in models/ and launch/ that needs the ambient mesh goes through
-this module so the rest of the codebase is version-agnostic.  Callers treat
-the return value of `get_abstract_mesh()` uniformly: it is either None or a
-mesh-like object with `.empty`, `.axis_names` and `.shape`.
+Everything in models/, launch/ and serving/ that needs the ambient mesh, a
+cost analysis, a scoped 64-bit mode or the persistent compilation cache
+goes through this module, so a JAX upgrade touches one file
+(`tools/lint_invariants.py` enforces the mesh part).  The installed
+release is JAX 0.9.
 """
 from __future__ import annotations
 
 import contextlib
+import os
+import pathlib
 from typing import Any, Sequence
 
 import jax
 
+# fixed cache path inside the checkout (gitignored): the directory is part
+# of the cache key, so it must not move between runs
+DEFAULT_CACHE_DIR = (pathlib.Path(__file__).resolve().parents[3]
+                     / "benchmarks" / "results" / ".xla_cache")
+
 
 def get_abstract_mesh() -> Any:
-    """The ambient mesh, or None when none is installed.
-
-    On 0.5.x+ this is `jax.sharding.get_abstract_mesh()` (an AbstractMesh,
-    possibly empty); on 0.4.x it is the thread-resources physical mesh set
-    by `with mesh:` (a Mesh, possibly empty).  Both expose `.empty`,
-    `.axis_names` and `.shape`, which is all our call sites use.
-    """
-    fn = getattr(jax.sharding, "get_abstract_mesh", None)
-    if fn is not None:
-        return fn()
-    from jax._src import mesh as _mesh_lib
-    return _mesh_lib.thread_resources.env.physical_mesh
+    """The ambient mesh (an `AbstractMesh`, possibly empty)."""
+    return jax.sharding.get_abstract_mesh()
 
 
 def set_mesh(mesh) -> contextlib.AbstractContextManager:
-    """Context manager installing `mesh` as the ambient mesh.
-
-    0.5.x+: `jax.sharding.set_mesh` (or `use_mesh` on the releases that
-    spelled it that way).  0.4.x: `with mesh:` installs the physical mesh,
-    which `with_sharding_constraint` resolves against.
-    """
-    for name in ("set_mesh", "use_mesh"):
-        fn = getattr(jax.sharding, name, None)
-        if fn is not None:
-            return fn(mesh)
-    return mesh  # Mesh is itself a context manager on 0.4.x
+    """Context manager installing `mesh` as the ambient mesh."""
+    return jax.sharding.set_mesh(mesh)
 
 
 def cost_analysis(compiled) -> dict:
-    """Flat cost dict from a compiled executable.
-
-    jaxlib 0.4.x returns a list of per-device dicts (one entry on
-    single-controller runs); 0.5.x+ returns the dict directly.
-    """
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost or {}
+    """Flat cost dict from a compiled executable."""
+    return compiled.cost_analysis() or {}
 
 
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str],
               **kwargs):
-    """`jax.make_mesh` with Auto axis_types where the release supports it."""
-    if hasattr(jax.sharding, "AxisType"):
-        kwargs.setdefault(
-            "axis_types",
-            (jax.sharding.AxisType.Auto,) * len(axis_names))
-    else:
-        kwargs.pop("axis_types", None)
+    """`jax.make_mesh` with Auto axis types."""
+    kwargs.setdefault("axis_types",
+                      (jax.sharding.AxisType.Auto,) * len(axis_names))
     return jax.make_mesh(tuple(axis_shapes), tuple(axis_names), **kwargs)
+
+
+def enable_x64() -> contextlib.AbstractContextManager:
+    """Context manager turning on 64-bit types for the block it wraps
+    (the fleet drain's float64 meters); the process default stays 32-bit."""
+    return jax.enable_x64(True)
+
+
+def enable_compile_cache() -> pathlib.Path:
+    """Turn on JAX's persistent compilation cache before the first compile.
+
+    With `JAX_COMPILATION_CACHE_DIR` set, JAX already reads that directory
+    and no other is set here; otherwise the cache lives at the fixed
+    `DEFAULT_CACHE_DIR`.  Every program is cached, however fast it
+    compiled.  Returns the directory in use."""
+    env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    path = pathlib.Path(env) if env else DEFAULT_CACHE_DIR
+    if not env:
+        path.mkdir(parents=True, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", str(path))
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    return path

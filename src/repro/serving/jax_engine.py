@@ -27,7 +27,7 @@ Layout / padding / masking
     (overflow / escalation / KV handoff) is byte-identical downstream.
 
 Parity contract: every meter expression replicates `energy.MeterBank`
-operation-for-operation in float64 (`jax.experimental.enable_x64` is
+operation-for-operation in float64 (`repro.models.compat.enable_x64` is
 scoped to the drain so the model-mode f32 default is untouched).  The only
 divergence is accumulation *order* on multi-slot chunk spills (the numpy
 slow path charges sequentially; the kernel sums a masked cumsum), which is
@@ -46,21 +46,16 @@ from __future__ import annotations
 
 from typing import Dict, List, Optional, Sequence
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 
 from repro.core.timeline import (EV_COMPLETE, EV_ESCALATE, EV_FIRST_TOKEN,
                                  EV_HANDOFF, EV_OVERFLOW)
+from repro.models.compat import enable_x64
 
 from .engine import _LCG_A, _LCG_C, _NEVER, DrainTruncatedError
 from .soa import BatchedPoolEngine
-
-try:
-    import jax
-    import jax.numpy as jnp
-    from jax.experimental import enable_x64
-except ImportError:                                    # pragma: no cover
-    jax = None          # numpy-only environments (the perf-regression CI
-    #                     job): constructing a JaxPoolEngine raises.
 
 _EV_NONE, _EV_DONE, _EV_OVERFLOW, _EV_ESCALATE, _EV_HANDOFF = 0, 1, 2, 3, 4
 
@@ -463,18 +458,8 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
     return jax.lax.while_loop(cond, body, st0)
 
 
-_DRAIN_CACHE: Dict[tuple, object] = {}
-
-
-def _get_drain(phase: str, n_slots_pad: int):
-    key = (phase, n_slots_pad)
-    fn = _DRAIN_CACHE.get(key)
-    if fn is None:
-        from functools import partial
-        fn = jax.jit(partial(
-            _drain_one, phase=phase, n_slots_pad=n_slots_pad))
-        _DRAIN_CACHE[key] = fn
-    return fn
+# one compiled program per (phase, n_slots_pad) and argument shapes
+_drain = jax.jit(_drain_one, static_argnames=("phase", "n_slots_pad"))
 
 
 # --------------------------------------------------------------------------
@@ -510,8 +495,6 @@ def drain_engines(engines: Sequence["JaxPoolEngine"], *,
     a single-core CPU runner each distinct signature costs a ~2 s XLA
     build — which is why callers that sweep hundreds of cells
     (benchmarks/fleet_grid_bench.py) pin a survey-derived class list."""
-    if jax is None:
-        raise RuntimeError("jax is not installed; use the numpy engine")
     groups: Dict[tuple, List[JaxPoolEngine]] = {}
     packed = {}
     for eng in engines:
@@ -552,7 +535,7 @@ def drain_engines(engines: Sequence["JaxPoolEngine"], *,
                         a[off:off + n] = r
                     off += n
                 merged[k] = jnp.asarray(a)
-            out = _get_drain(phase, s_pad)(merged)
+            out = _drain(merged, phase=phase, n_slots_pad=s_pad)
             out = {k: np.asarray(v) for k, v in out.items()}
             off = 0
             for eng in engs:
@@ -583,10 +566,6 @@ class JaxPoolEngine(BatchedPoolEngine):
     the results, which this method then just finalizes."""
 
     def __init__(self, **kw):
-        if jax is None:
-            raise RuntimeError(
-                "JaxPoolEngine needs jax; this environment is numpy-only "
-                "(FleetSim(engine='numpy') is the oracle path)")
         super().__init__(**kw)
         if self.phase != "prefill" and not self.prefill_chunk:
             raise NotImplementedError(

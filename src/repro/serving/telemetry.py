@@ -192,14 +192,17 @@ def reconcile_energy(rec: TraceRecorder, meters: Iterable) \
     """Per-phase {trace, meter, rel_err} comparing the charge channel
     against the meters' lifetime totals.  The hooks record the *same*
     float64 values the meters accumulate, so rel_err is float-rounding
-    small; the trace report gates every phase at <0.1%."""
+    small; the trace report gates every phase at <0.1%.  The meters'
+    decode is a residual of their total (`phase_totals`) and carries the
+    total's rounding, so its error is taken relative to the total."""
     trace = rec.energy_by_phase()
     meter = phase_totals(meters)
     out = {}
     for phase in ("total", "decode", "prefill", "idle", "handoff",
                   "dispatch"):
         t, m = trace[phase], meter[phase]
-        denom = max(abs(m), 1e-12)
+        scale = meter["total"] if phase == "decode" else m
+        denom = max(abs(m), abs(scale), 1e-12)
         out[phase] = {"trace_j": t, "meter_j": m,
                       "rel_err": abs(t - m) / denom if (t or m) else 0.0}
     return out

@@ -19,7 +19,8 @@ def test_int8_flash_decode(B, H, K, D, T, bt):
     k = jax.random.normal(ks_[1], (B, T, K, D))
     v = jax.random.normal(ks_[2], (B, T, K, D))
     lengths = jax.random.randint(ks_[3], (B,), 1, T + 1)
-    kq, vq, ks8, vs8 = quantize_kv(k, v)
+    # the kernel reads the head-major (B, K, T, D) cache
+    kq, vq, ks8, vs8 = quantize_kv(k.swapaxes(1, 2), v.swapaxes(1, 2))
     out = flash_decode_int8(q, kq, vq, ks8, vs8, lengths, block_t=bt)
     ref = flash_decode_ref(q, k, v, lengths)
     # int8 KV quantization error: attention output within ~1% relative
@@ -29,7 +30,7 @@ def test_int8_flash_decode(B, H, K, D, T, bt):
 
 def test_quantize_roundtrip_error():
     rng = jax.random.PRNGKey(0)
-    k = jax.random.normal(rng, (2, 64, 4, 64)) * 3.0
+    k = jax.random.normal(rng, (2, 4, 64, 64)) * 3.0
     kq, _, ks, _ = quantize_kv(k, k)
     deq = kq.astype(jnp.float32) * ks[..., None]
     rel = float(jnp.abs(deq - k).max() / jnp.abs(k).max())
@@ -47,7 +48,7 @@ def test_int8_matches_fp_kernel_when_exact():
     B, H, K, D, T = 1, 2, 2, 32, 40
     rng = jax.random.PRNGKey(3)
     q = jax.random.normal(rng, (B, H, D))
-    base = jnp.sign(jax.random.normal(rng, (B, T, K, D)))  # +-1 exact
+    base = jnp.sign(jax.random.normal(rng, (B, K, T, D)))  # +-1 exact
     lengths = jnp.array([T])
     kq, vq, ks, vs = quantize_kv(base, base)
     a = flash_decode_int8(q, kq, vq, ks, vs, lengths, block_t=16)

@@ -1,7 +1,9 @@
 """Pallas kernels (interpret=True) vs pure-jnp oracles: shape/dtype sweeps.
 
 Per the brief: for each kernel, sweep shapes/dtypes and assert_allclose
-against the ref.py oracle.
+against the ref.py oracle.  The kernels take head-major inputs
+(B, heads, S, hd); the oracles are sequence-major (B, S, heads, hd), so
+each case draws sequence-major data and hands the kernel `hm(x)`.
 """
 import jax
 import jax.numpy as jnp
@@ -17,6 +19,11 @@ from repro.kernels.ref import flash_decode_ref, mamba_scan_ref, wkv6_ref
 ATOL = {jnp.float32: 2e-5, jnp.bfloat16: 5e-2}
 
 
+def hm(x):
+    """Sequence-major <-> head-major (swaps axes 1 and 2)."""
+    return x.swapaxes(1, 2)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize("B,H,K,D,T,bt", [
     (2, 8, 4, 64, 100, 64), (1, 16, 8, 128, 300, 256),
@@ -29,7 +36,7 @@ def test_flash_decode_sweep(B, H, K, D, T, bt, dtype):
     k = jax.random.normal(ks[1], (B, T, K, D), dtype)
     v = jax.random.normal(ks[2], (B, T, K, D), dtype)
     lengths = jax.random.randint(ks[3], (B,), 1, T + 1)
-    out = flash_decode(q, k, v, lengths, block_t=bt)
+    out = flash_decode(q, hm(k), hm(v), lengths, block_t=bt)
     ref = flash_decode_ref(q.astype(jnp.float32), k.astype(jnp.float32),
                            v.astype(jnp.float32), lengths)
     np.testing.assert_allclose(out.astype(jnp.float32), ref,
@@ -48,11 +55,11 @@ def test_flash_decode_lengths_property(T, B):
     k = jax.random.normal(ks[1], (B, T, K, D))
     v = jax.random.normal(ks[2], (B, T, K, D))
     lengths = jax.random.randint(ks[3], (B,), 1, T + 1)
-    out1 = flash_decode(q, k, v, lengths, block_t=32)
+    out1 = flash_decode(q, hm(k), hm(v), lengths, block_t=32)
     mask = jnp.arange(T)[None, :, None, None] < lengths[:, None, None, None]
     k2 = jnp.where(mask, k, 999.0)   # garbage outside the valid range
     v2 = jnp.where(mask, v, -999.0)
-    out2 = flash_decode(q, k2, v2, lengths, block_t=32)
+    out2 = flash_decode(q, hm(k2), hm(v2), lengths, block_t=32)
     np.testing.assert_allclose(out1, out2, atol=1e-5)
 
 
@@ -67,7 +74,8 @@ def test_mamba_scan_sweep(B, S, nh, hd, ds, ch, dtype):
     Bm = jax.random.normal(ks[1], (B, S, ds), dtype)
     Cm = jax.random.normal(ks[2], (B, S, ds), dtype)
     lA = -jnp.abs(jax.random.normal(ks[3], (B, S, nh))) * 0.5
-    y, st_ = mamba_scan(xt, Bm, Cm, lA, chunk=ch)
+    y, st_ = mamba_scan(hm(xt), Bm, Cm, lA.swapaxes(1, 2), chunk=ch)
+    y = hm(y)
     yr, sr = mamba_scan_ref(xt.astype(jnp.float32), Bm.astype(jnp.float32),
                             Cm.astype(jnp.float32), lA)
     np.testing.assert_allclose(y.astype(jnp.float32), yr,
@@ -87,7 +95,8 @@ def test_wkv6_sweep(B, S, H, hd, ch, wmin):
     r, k, v = (jax.random.normal(ks[i], (B, S, H, hd)) for i in range(3))
     w = jax.random.uniform(ks[3], (B, S, H, hd), minval=wmin, maxval=1.0)
     u = 0.5 * jax.random.normal(ks[4], (H, hd))
-    y, st_ = wkv6(r, k, v, w, u, chunk=ch)
+    y, st_ = wkv6(hm(r), hm(k), hm(v), hm(w), u, chunk=ch)
+    y = hm(y)
     yr, sr = wkv6_ref(r, k, v, w, u)
     assert jnp.isfinite(y).all()
     np.testing.assert_allclose(y, yr, atol=2e-3, rtol=1e-3)
@@ -98,8 +107,8 @@ def test_ops_dispatch_modes():
     rng = jax.random.PRNGKey(0)
     ks = jax.random.split(rng, 4)
     q = jax.random.normal(ks[0], (2, 4, 32))
-    k = jax.random.normal(ks[1], (2, 50, 2, 32))
-    v = jax.random.normal(ks[2], (2, 50, 2, 32))
+    k = jax.random.normal(ks[1], (2, 2, 50, 32))
+    v = jax.random.normal(ks[2], (2, 2, 50, 32))
     lengths = jnp.array([50, 13])
     a = ops.decode_attention(q, k, v, lengths, force="ref")
     b = ops.decode_attention(q, k, v, lengths, force="interpret")
