@@ -77,6 +77,7 @@ from .request import (Request, latency_percentiles as _percentiles,
                       latency_percentiles_arrays, sample_trace)
 from .router import ContextRouter, RouterPolicy
 from .soa import BatchedPoolEngine
+from .telemetry import host_span, host_spanned
 
 
 def trace_requests(workload: Workload, n: int, *, seed: int = 0,
@@ -551,6 +552,7 @@ class FleetSim:
     # them across many sims so each stage's JAX pools batch into one
     # compiled drain.
 
+    @host_spanned("fleet.route")
     def begin_run(self, requests: List[Request], *,
                   warmup_frac: float = 0.35,
                   reuse: Optional[Dict[str, PoolSummary]] = None) -> None:
@@ -654,6 +656,7 @@ class FleetSim:
         grp.engine.sort_queues()    # keep queues time-sorted for the
         return grp.engine           # head-gated admission
 
+    @host_spanned("fleet.flow")
     def drain_role(self, role: str, *,
                    max_iters: int = 20_000_000) -> None:
         """Drain one prepared pool (or adopt its reused snapshot) and
@@ -720,6 +723,7 @@ class FleetSim:
         self.summaries[role] = grp.summarize(rs["role_idx"], outbox,
                                              n_over, n_esc, n_hand)
 
+    @host_spanned("fleet.report")
     def finish_run(self) -> Dict[str, dict]:
         assert not any(self._run_state["inbox"].values()), \
             "undelivered cross-pool requests"
@@ -858,6 +862,7 @@ class SimVsAnalytical:
                     migrations=f["migrations"])
 
 
+@host_spanned("fleet.prepare")
 def prepare_spec(spec: TopologySpec, workload: Workload, *,
                  n_requests: int = 4000, seed: int = 0,
                  arrival_rate: Optional[float] = None,
@@ -884,20 +889,23 @@ def prepare_spec(spec: TopologySpec, workload: Workload, *,
     """
     if arrival_rate is not None and arrival_rate != workload.arrival_rate:
         workload = dataclasses.replace(workload, arrival_rate=arrival_rate)
-    policy, plan, registry = spec.build(workload,
-                                        pool_overrides=pool_overrides)
+    with host_span("prepare.build"):
+        policy, plan, registry = spec.build(workload,
+                                            pool_overrides=pool_overrides)
     as_policy = None
     if autoscale:
         as_policy = spec.autoscale if spec.autoscale is not None \
             else AutoscalePolicy()
-    sim = FleetSim(policy, plan, registry=registry,
-                   prefill_chunk=prefill_chunk, rng_seed=seed,
-                   engine=engine, autoscale=as_policy,
-                   telemetry=telemetry)
+    with host_span("prepare.sim"):
+        sim = FleetSim(policy, plan, registry=registry,
+                       prefill_chunk=prefill_chunk, rng_seed=seed,
+                       engine=engine, autoscale=as_policy,
+                       telemetry=telemetry)
     sim.workload_name = workload.name     # grid-driver report labels
     sim.topology_kind = spec.kind
-    reqs = trace_requests(workload, n_requests, seed=seed,
-                          max_total=spec.max_window, trace=trace)
+    with host_span("prepare.requests"):
+        reqs = trace_requests(workload, n_requests, seed=seed,
+                              max_total=spec.max_window, trace=trace)
     return sim, reqs, plan
 
 
@@ -1007,22 +1015,24 @@ def run_fleet_grid(scenarios: List[Tuple[FleetSim, List[Request], object]],
         sim.begin_run(reqs, warmup_frac=warmup_frac)
     n_stages = max(len(sim.order) for sim, _, _ in scenarios)
     for k in range(n_stages):
-        staged = []
-        for sim, _, _ in scenarios:
-            if k >= len(sim.order):
-                continue
-            eng = sim.pre_role(sim.order[k])
-            if isinstance(eng, JaxPoolEngine):
-                staged.append(eng)
-        if staged:
-            drain_engines(staged, max_iters=max_iters,
-                          pad_floors=pad_floors)
-        for sim, _, _ in scenarios:
-            if k < len(sim.order):
-                sim.drain_role(sim.order[k], max_iters=max_iters)
+        with host_span("grid.stage", k=k):
+            staged = []
+            for sim, _, _ in scenarios:
+                if k >= len(sim.order):
+                    continue
+                eng = sim.pre_role(sim.order[k])
+                if isinstance(eng, JaxPoolEngine):
+                    staged.append(eng)
+            if staged:
+                drain_engines(staged, max_iters=max_iters,
+                              pad_floors=pad_floors)
+            for sim, _, _ in scenarios:
+                if k < len(sim.order):
+                    sim.drain_role(sim.order[k], max_iters=max_iters)
     out = []
-    for sim, _, plan in scenarios:
-        report = sim.finish_run()
-        out.append(_sim_vs_analytical(
-            sim, plan, sim.topology_kind, sim.workload_name, report))
+    with host_span("grid.report"):
+        for sim, _, plan in scenarios:
+            report = sim.finish_run()
+            out.append(_sim_vs_analytical(
+                sim, plan, sim.topology_kind, sim.workload_name, report))
     return out

@@ -56,6 +56,7 @@ from repro.models.compat import enable_x64
 
 from .engine import _LCG_A, _LCG_C, _NEVER, DrainTruncatedError
 from .soa import BatchedPoolEngine
+from .telemetry import host_count, host_span, host_spanned
 
 _EV_NONE, _EV_DONE, _EV_OVERFLOW, _EV_ESCALATE, _EV_HANDOFF = 0, 1, 2, 3, 4
 
@@ -125,6 +126,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         **{k: (zero_i(I) if k in ("tokens", "m_tokens", "prefill_tokens")
                else zero_f(I)) for k in _METER_KEYS})
 
+    @jax.named_scope("emit")
     def emit(st, mask, kind, time_val, ngen=None, first=None):
         """Record one terminal/drain event per masked slot into the
         queue-indexed out arrays.  Event masks/values live in slot space
@@ -185,6 +187,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         st["prefill_tokens"] += take.sum(1, dtype=jnp.int32)
         return st, sim + dt.sum(1), t_before + dt
 
+    @jax.named_scope("admit")
     def admit(st, sim):
         """Head-gated FIFO admission of the ready queue prefix into the
         lowest free slots (chunked mode never advances the clock here, so
@@ -227,6 +230,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         st["qpos"] = st["qpos"] + n_admit
         return st
 
+    @jax.named_scope("decode_step")
     def decode_step(st, sim):
         n_occ = st["active"].sum(1, dtype=i32)
         dec = st["active"] & (st["prefill_left"] == 0)
@@ -301,6 +305,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         st["prefill_left"] = st["prefill_left"] - take
         return st, sim, n_occ
 
+    @jax.named_scope("coast")
     def coast(st, sim):
         """Event-free fast-forward for decode rows.  When a row's in-flight
         set is static — no slot will reach done/escalate/ceiling, no prompt
@@ -388,6 +393,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         st["m_gen"] += jnp.where(coasted & win[:, None], jn[:, None], 0)
         return st, sim + adv
 
+    @jax.named_scope("prefill_step")
     def prefill_step(st, sim):
         """Prefill-phase lockstep: drain up to one chunk across occupied
         slots oldest-first (stable sort on ready_ts, ties to the lowest
@@ -416,14 +422,13 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         st["esc"] = jnp.where(drained, _NEVER, st["esc"])
         return st, sim, n_occ
 
-    def body(st):
-        st = dict(st)
-        sim = st["sim_time"]
+    @jax.named_scope("idle_skip")
+    def idle_skip(st, sim):
+        """Event-driven idle skip (respect_arrival only): rows with nothing
+        in flight jump to their queue's next arrival, idle power accruing
+        over the gap."""
         active_any = st["active"].any(1)
         has_q = st["qpos"] < p["qlen"]
-        # event-driven idle skip (respect_arrival only): rows with nothing
-        # in flight jump to their queue's next arrival, idle power
-        # accruing over the gap
         rem = (qidx >= st["qpos"][:, None]) & (qidx < p["qlen"][:, None])
         min_ready = jnp.min(jnp.where(rem, p["q_ready"], jnp.inf), axis=1)
         dt = min_ready - sim
@@ -436,7 +441,13 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         st["m_idle_joules"] += e_in
         st["joules"] += jnp.where(do, e, 0.0)
         st["idle_joules"] += jnp.where(do, e, 0.0)
-        sim = sim + dtc
+        return st, sim + dtc
+
+    # each phase of the loop carries a named scope, so the device trace's
+    # ops keep a stable name (".../decode_step/emit/...") across refactors
+    def body(st):
+        st = dict(st)
+        st, sim = idle_skip(st, st["sim_time"])
         t_start = sim
         st = admit(st, sim)
         if phase == "prefill":
@@ -451,6 +462,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         st["it"] = st["it"] + 1
         return st
 
+    @jax.named_scope("cond")
     def cond(st):
         alive = st["active"].any() | (st["qpos"] < p["qlen"]).any()
         return alive & (st["it"] < p["max_iters"])
@@ -511,48 +523,79 @@ def drain_engines(engines: Sequence["JaxPoolEngine"], *,
         groups.setdefault((eng.phase, *dims), []).append(eng)
     with enable_x64():
         for (phase, i_floor, s_pad, q_pad), engs in groups.items():
+            rows = [packed[id(e)] for e in engs]
             i_tot = sum(e.instances for e in engs)
             i_pad = _bucket(max(i_tot, i_floor))
-            merged = {}
-            for k in packed[id(engs[0])]:
-                rows = [packed[id(e)][k] for e in engs]
-                if np.ndim(rows[0]) == 0:       # max_iters: shared scalar
-                    merged[k] = jnp.asarray(max(rows))
-                    continue
-                if rows[0].ndim == 2:
-                    fill = np.inf if k == "q_ready" else (
-                        _NEVER if k == "q_esc" else 0)
-                    a = np.full((i_pad, q_pad), fill, rows[0].dtype)
-                else:
-                    a = np.full((i_pad,),
-                                1 if k in _PAD_ONES else 0, rows[0].dtype)
-                off = 0
-                for r in rows:
-                    n = r.shape[0]
-                    if r.ndim == 2:
-                        a[off:off + n, :r.shape[1]] = r
-                    else:
-                        a[off:off + n] = r
-                    off += n
-                merged[k] = jnp.asarray(a)
-            out = _drain(merged, phase=phase, n_slots_pad=s_pad)
-            out = {k: np.asarray(v) for k, v in out.items()}
-            off = 0
-            for eng in engs:
-                I, S = eng.instances, eng.n_slots
-                Q = packed[id(eng)]["q_ready"].shape[1]
-                res = {}
-                for k, v in out.items():
-                    if v.ndim == 0:             # the shared `it` counter
-                        res[k] = v
-                        continue
-                    s = v[off:off + I]
-                    if s.ndim == 2:
-                        s = s[:, :Q] if (k.startswith("out_")
-                                         or k == "q_slot") else s[:, :S]
-                    res[k] = s
-                eng._staged = res
-                off += I
+            with host_span("drain.group", phase=phase, rows=i_tot,
+                           rows_padded=i_pad, s_pad=s_pad, q_pad=q_pad):
+                with host_span("drain.stack"):
+                    merged = _stack(rows, i_pad, q_pad)
+                with host_span("drain.launch"):
+                    out = _drain(jax.device_put(merged), phase=phase,
+                                 n_slots_pad=s_pad)
+                with host_span("drain.wait"):
+                    out = jax.block_until_ready(out)
+                with host_span("drain.fetch"):
+                    out = {k: np.asarray(v) for k, v in out.items()}
+                # iteration-weighted real and padded queue entries: the
+                # loop gathers over every (i_pad, q_pad) entry each step
+                it = int(out["it"])
+                host_count("drain.groups", 1)
+                host_count("drain.iters", it)
+                host_count("drain.entry_iters",
+                           it * sum(int(r["qlen"].sum()) for r in rows))
+                host_count("drain.entry_iters_padded", it * i_pad * q_pad)
+                with host_span("drain.split"):
+                    _split(out, engs, rows)
+
+
+def _stack(rows: List[dict], i_pad: int, q_pad: int) -> Dict[str, np.ndarray]:
+    """Concatenate packed pools along the instance axis, padded to
+    (i_pad,) and (i_pad, q_pad) host arrays."""
+    merged = {}
+    for k in rows[0]:
+        parts = [r[k] for r in rows]
+        if np.ndim(parts[0]) == 0:       # max_iters: shared scalar
+            merged[k] = max(parts)
+            continue
+        if parts[0].ndim == 2:
+            fill = np.inf if k == "q_ready" else (
+                _NEVER if k == "q_esc" else 0)
+            a = np.full((i_pad, q_pad), fill, parts[0].dtype)
+        else:
+            a = np.full((i_pad,),
+                        1 if k in _PAD_ONES else 0, parts[0].dtype)
+        off = 0
+        for r in parts:
+            n = r.shape[0]
+            if r.ndim == 2:
+                a[off:off + n, :r.shape[1]] = r
+            else:
+                a[off:off + n] = r
+            off += n
+        merged[k] = a
+    return merged
+
+
+def _split(out: Dict[str, np.ndarray], engs: Sequence["JaxPoolEngine"],
+           rows: List[dict]) -> None:
+    """Stage each engine's row span of a group's drained outputs on it."""
+    off = 0
+    for eng, packed in zip(engs, rows):
+        I, S = eng.instances, eng.n_slots
+        Q = packed["q_ready"].shape[1]
+        res = {}
+        for k, v in out.items():
+            if v.ndim == 0:             # the shared `it` counter
+                res[k] = v
+                continue
+            s = v[off:off + I]
+            if s.ndim == 2:
+                s = s[:, :Q] if (k.startswith("out_")
+                                 or k == "q_slot") else s[:, :S]
+            res[k] = s
+        eng._staged = res
+        off += I
 
 
 class JaxPoolEngine(BatchedPoolEngine):
@@ -576,6 +619,7 @@ class JaxPoolEngine(BatchedPoolEngine):
 
     # --- pack -----------------------------------------------------------
 
+    @host_spanned("drain.pack")
     def _pack(self, max_iters: int) -> Dict[str, np.ndarray]:
         """Freeze queues into device-ready arrays + scalar params (the
         scenario pytree drain_engines stacks on the vmap axis)."""
@@ -634,6 +678,7 @@ class JaxPoolEngine(BatchedPoolEngine):
 
     # --- reconstruct ----------------------------------------------------
 
+    @host_spanned("drain.replay")
     def _finalize(self, res: Dict[str, np.ndarray],
                   max_iters: int) -> None:
         alive = bool(res["active"].any()) \

@@ -35,9 +35,23 @@ Two channels, two cost classes:
 `build_timeline` bins both channels onto a fixed sim-time grid
 (`core.timeline.MetricsTimeline`); `to_perfetto` renders events as one
 Perfetto track per pool/instance with power/occupancy counter tracks.
+
+The **host channel** times the program itself, in wall-clock time rather
+than simulated time: `host_span(name, **args)` brackets a layer of the
+fleet path (spec to sim, routing, each drain group's stack / launch /
+wait / fetch / split, replay, flow) and `host_count(name, n)` adds to a
+counter (drain iterations, real and padded queue entries).  Both are
+no-ops until `host_tracing()` switches a process-wide `HostRecorder` on:
+then each span is kept in memory with its parent and also opened as a
+`jax.profiler.TraceAnnotation`, so the same interval lands on the
+profiler's host plane beside the device's ops.  Its times come from
+`time.time_ns()`, the real-time clock the profiler's host events use.
 """
 from __future__ import annotations
 
+import contextlib
+import functools
+import time
 from typing import Dict, Iterable, List, Optional, Tuple
 
 import numpy as np
@@ -49,7 +63,8 @@ from repro.core.timeline import (
     instant_event, meta_event, span_event)
 
 __all__ = ["TraceRecorder", "build_timeline", "to_perfetto",
-           "phase_totals", "reconcile_energy"]
+           "phase_totals", "reconcile_energy", "HostRecorder",
+           "host_tracing", "host_span", "host_spanned", "host_count"]
 
 
 def _chunk_total(ref, val) -> float:
@@ -165,6 +180,97 @@ class TraceRecorder:
             if dispatch is not None:
                 out["dispatch"] += _chunk_total(start, dispatch)
         return out
+
+
+# --- host channel: wall-clock spans and counters of the program ----------
+
+class HostRecorder:
+    """Spans and counters of one traced stretch of the program.
+
+    `spans` holds `[name, start_ns, end_ns, parent, args]` in the order
+    the spans opened; `parent` is the index of the enclosing span (None at
+    the top) and the times are `time.time_ns()`.  The fleet path runs on
+    one thread, so the open spans form one stack."""
+
+    __slots__ = ("spans", "counters", "_open", "_annotate")
+
+    def __init__(self, annotate):
+        self.spans: List[list] = []
+        self.counters: Dict[str, int] = {}
+        self._open: List[int] = []
+        self._annotate = annotate       # jax.profiler.TraceAnnotation
+
+
+class _HostSpan:
+    __slots__ = ("rec", "name", "args", "idx", "ann")
+
+    def __init__(self, rec: HostRecorder, name: str, args: dict):
+        self.rec, self.name, self.args = rec, name, args
+
+    def __enter__(self):
+        rec = self.rec
+        self.ann = rec._annotate(self.name, **self.args)
+        self.ann.__enter__()
+        self.idx = len(rec.spans)
+        rec.spans.append([self.name, time.time_ns(), None,
+                          rec._open[-1] if rec._open else None, self.args])
+        rec._open.append(self.idx)
+        return self
+
+    def __exit__(self, *exc):
+        rec = self.rec
+        rec.spans[self.idx][2] = time.time_ns()
+        rec._open.pop()
+        return self.ann.__exit__(*exc)
+
+
+_host: Optional[HostRecorder] = None
+_OFF = contextlib.nullcontext()
+
+
+@contextlib.contextmanager
+def host_tracing():
+    """Switch the process-wide host recorder on for the `with` block and
+    yield it.  jax is imported here, so the numpy engines never need it
+    while tracing is off."""
+    global _host
+    from jax.profiler import TraceAnnotation
+    prev, rec = _host, HostRecorder(TraceAnnotation)
+    _host = rec
+    try:
+        yield rec
+    finally:
+        _host = prev
+
+
+def host_span(name: str, **args):
+    """A context that records one span while a recorder is on, else the
+    shared no-op context (nothing allocated)."""
+    rec = _host
+    if rec is None:
+        return _OFF
+    return _HostSpan(rec, name, args)
+
+
+def host_spanned(name: str):
+    """Decorator form of `host_span` for a span that is a whole call."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def spanned(*a, **kw):
+            rec = _host
+            if rec is None:
+                return fn(*a, **kw)
+            with _HostSpan(rec, name, {}):
+                return fn(*a, **kw)
+        return spanned
+    return wrap
+
+
+def host_count(name: str, n: int) -> None:
+    """Add `n` to a counter of the active recorder; no-op when off."""
+    rec = _host
+    if rec is not None:
+        rec.counters[name] = rec.counters.get(name, 0) + n
 
 
 # --- meter-side totals + reconciliation ---------------------------------

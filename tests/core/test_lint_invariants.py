@@ -89,3 +89,30 @@ def test_print_outside_serving_and_opt_out_are_exempt(tmp_path):
           'print("summary")  # lint: allow-print\n'
           "self.blueprint(x)\nfoo.print_tree()\n")
     assert lint._scan(root) == []
+
+
+def test_host_timing_in_serving_outside_telemetry_fires(tmp_path):
+    """Wall-clock reads and profiler annotations in the serving stack go
+    through serving.telemetry's host channel, nowhere else."""
+    root = _tree(tmp_path, "src/repro/serving/rogue.py",
+                 "import time\n"
+                 "t0 = time.perf_counter()\n"
+                 "import jax.profiler\n"
+                 "with jax.profiler.TraceAnnotation('x'):\n"
+                 "    t1 = time.time_ns()\n"
+                 "from time import perf_counter\n")
+    got = lint._scan(root)
+    assert [line for _, line, _ in got] == [2, 3, 4, 5, 6]
+    assert all("host_span" in msg for _, _, msg in got)
+
+
+def test_host_timing_in_telemetry_and_outside_serving_is_exempt(tmp_path):
+    """The recorder itself reads the clock and opens the annotations;
+    benchmarks time freely; `sim_time` and `time_s` names never fire."""
+    root = _tree(tmp_path, "src/repro/serving/telemetry.py",
+                 "from jax.profiler import TraceAnnotation\n"
+                 "t = time.time_ns()\n")
+    _tree(root, "benchmarks/bench.py", "t0 = time.perf_counter()\n")
+    _tree(root, "src/repro/serving/engine.py",
+          "self.sim_time.time = 1\nreq.ready_time = time_s\n")
+    assert lint._scan(root) == []

@@ -1,0 +1,166 @@
+"""The host channel of serving.telemetry: off it is one shared no-op, on it
+records nested spans and counters, and a compiled FleetOpt grid records
+every layer of the fleet path with its parent, its drain counters equal
+what the drain returned, and its reports do not move."""
+import json
+
+import pytest
+
+from repro.core.modelspec import LLAMA31_70B
+from repro.core.profiles import H100_LLAMA70B
+from repro.core.topospec import TopologySpec
+from repro.core.workloads import AZURE
+from repro.serving import prepare_spec, run_fleet_grid, telemetry
+from repro.serving.jax_engine import JaxPoolEngine, _bucket
+
+# span -> the span it opens under in a `run_fleet_grid` call
+PARENTS = {
+    "fleet.prepare": None,
+    "prepare.build": "fleet.prepare",
+    "prepare.sim": "fleet.prepare",
+    "prepare.requests": "fleet.prepare",
+    "fleet.route": None,
+    "grid.stage": None,
+    "drain.pack": "grid.stage",
+    "drain.group": "grid.stage",
+    "drain.stack": "drain.group",
+    "drain.launch": "drain.group",
+    "drain.wait": "drain.group",
+    "drain.fetch": "drain.group",
+    "drain.split": "drain.group",
+    "fleet.flow": "grid.stage",
+    "drain.replay": "fleet.flow",
+    "grid.report": None,
+    "fleet.report": "grid.report",
+}
+
+
+def test_off_is_the_shared_noop_and_records_nothing():
+    assert telemetry._host is None
+    a, b = telemetry.host_span("x", k=1), telemetry.host_span("y")
+    assert a is b is telemetry._OFF
+    with a:
+        telemetry.host_count("n", 3)
+    with telemetry.host_tracing() as rec:
+        pass
+    assert rec.spans == [] and rec.counters == {}
+    assert telemetry._host is None
+
+
+def test_nesting_sets_parents_and_counters_add():
+    with telemetry.host_tracing() as rec:
+        with telemetry.host_span("outer", k=2):
+            with telemetry.host_span("inner"):
+                telemetry.host_count("n", 3)
+            with telemetry.host_span("inner"):
+                telemetry.host_count("n", 4)
+        with telemetry.host_span("next"):
+            pass
+    assert telemetry._host is None
+    assert [(s[0], s[3], s[4]) for s in rec.spans] == [
+        ("outer", None, {"k": 2}), ("inner", 0, {}), ("inner", 0, {}),
+        ("next", None, {})]
+    assert rec.counters == {"n": 7}
+    for name, t0, t1, parent, _ in rec.spans:
+        assert t0 <= t1
+        if parent is not None:
+            assert rec.spans[parent][1] <= t0 and t1 <= rec.spans[parent][2]
+
+
+def test_spanned_decorator_and_exceptions_close_spans():
+    @telemetry.host_spanned("call")
+    def f(x):
+        with telemetry.host_span("fails"):
+            raise ValueError(x)
+
+    with telemetry.host_tracing() as rec:
+        with pytest.raises(ValueError):
+            f(1)
+        with telemetry.host_span("after"):
+            pass
+    assert [(s[0], s[3]) for s in rec.spans] == [
+        ("call", None), ("fails", 0), ("after", None)]
+    assert all(s[2] is not None for s in rec.spans)
+    assert f.__name__ == "f"
+
+
+def _grid(monkeypatch=None, finals=None):
+    """A two-stage FleetOpt grid of two small scenarios on the compiled
+    engine; `finals` collects (engine, res) of every replayed drain."""
+    if finals is not None:
+        raw = JaxPoolEngine._finalize
+
+        def spy(self, res, max_iters):
+            finals.append((self, res))
+            return raw(self, res, max_iters)
+        monkeypatch.setattr(JaxPoolEngine, "_finalize", spy)
+    spec = TopologySpec.from_kind("fleetopt", H100_LLAMA70B, LLAMA31_70B,
+                                  b_short=4096)
+    scenarios = [prepare_spec(spec, AZURE, n_requests=300, seed=s,
+                              engine="jax") for s in (0, 1)]
+    res = run_fleet_grid(scenarios)
+    return [json.dumps(r.report, sort_keys=True, default=str) for r in res]
+
+
+@pytest.fixture(scope="module")
+def traced():
+    mp = pytest.MonkeyPatch()
+    finals = []
+    try:
+        with telemetry.host_tracing() as rec:
+            reports = _grid(mp, finals)
+    finally:
+        mp.undo()
+    return rec, reports, finals
+
+
+def test_grid_records_every_layer_with_its_parent(traced):
+    rec, _, _ = traced
+    seen = {}
+    for name, t0, t1, parent, args in rec.spans:
+        assert t1 is not None and t0 <= t1, name
+        seen.setdefault(name, set()).add(
+            None if parent is None else rec.spans[parent][0])
+    assert {n: p for n, ps in seen.items() for p in ps} == PARENTS
+    assert all(len(ps) == 1 for ps in seen.values())
+    stages = [s[4]["k"] for s in rec.spans if s[0] == "grid.stage"]
+    assert stages == [0, 1]
+    # each scenario prepares, routes and reports once; each pool replays
+    count = {n: sum(s[0] == n for s in rec.spans) for n in PARENTS}
+    assert count["fleet.prepare"] == count["fleet.route"] == 2
+    assert count["fleet.report"] == 2
+    assert count["drain.replay"] == count["drain.pack"] == 4
+    assert count["drain.group"] == rec.counters["drain.groups"] == 2
+
+
+def test_drain_counters_equal_what_the_drain_returned(traced):
+    """`drain.iters` is the loop's own `it`, once per compiled group (each
+    engine of a group is handed the same array); the entry counters are
+    it x real queue entries and it x the padded (I, Q) grid."""
+    rec, _, finals = traced
+    groups = {}
+    for eng, res in finals:
+        groups.setdefault(id(res["it"]), (res["it"], []))[1].append(eng)
+    assert len(groups) == rec.counters["drain.groups"]
+    iters = entries = padded = 0
+    shapes = []
+    for it, engs in groups.values():
+        it = int(it)
+        rows = sum(e.instances for e in engs)
+        q_pad = _bucket(max(1, int(engs[0].qlen.max())))
+        shapes.append((rows, _bucket(rows), q_pad))
+        iters += it
+        entries += it * sum(int(e.qlen.sum()) for e in engs)
+        padded += it * _bucket(rows) * q_pad
+    assert rec.counters["drain.iters"] == iters > 0
+    assert rec.counters["drain.entry_iters"] == entries > 0
+    assert rec.counters["drain.entry_iters_padded"] == padded > entries
+    # each group's span names the same rows and padded shape
+    assert sorted((s[4]["rows"], s[4]["rows_padded"], s[4]["q_pad"])
+                  for s in rec.spans if s[0] == "drain.group") \
+        == sorted(shapes)
+
+
+def test_grid_reports_are_bit_identical_with_the_recorder_off(traced):
+    _, reports_on, _ = traced
+    assert _grid() == reports_on

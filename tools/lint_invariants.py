@@ -1,9 +1,8 @@
 """Grep-style lint for the repo's structural invariants.
 
 Fast (no imports of the package, pure text scan) so CI can run it as a
-seconds-long job on every PR.  Two invariants, both established by the
-TopologySpec IR refactor and easy to erode one convenient `if` at a
-time:
+seconds-long job on every PR.  Three invariants, each easy to erode one
+convenient line at a time:
 
 1. **Topology kind dispatch is centralised.**  String-kind topology
    dispatch (``kind == "fleetopt"`` etc.) exists in exactly one place:
@@ -32,7 +31,12 @@ time:
    ad-hoc ``print(...)`` in the serving stack is either debug residue
    or a new side channel the trace schema doesn't know about; both are
    flagged.  (Benchmarks, tools and examples print freely — they are
-   the presentation layer, not the hot path.)
+   the presentation layer, not the hot path.)  Host timing likewise
+   goes through the recorder's host channel (``host_span`` /
+   ``host_count``): ``jax.profiler``, ``TraceAnnotation`` and
+   ``time.perf_counter`` / ``time.time`` under ``src/repro/serving/``
+   outside ``telemetry.py`` are flagged, so every wall-clock read of the
+   serving stack sits behind the one off-by-default switch.
 
 Run:  python tools/lint_invariants.py          (from the repo root)
 Exit: 0 clean, 1 with one ``path:line: message`` per violation.
@@ -64,6 +68,13 @@ _PRINT_CALL = re.compile(r"(?<![\w.])print\s*\(")
 _PRINT_SCOPE = "src/repro/serving/"
 _PRINT_OPT_OUT = "# lint: allow-print"
 
+# host timing in the serving stack outside the recorder's host channel
+_HOST_TIMING = re.compile(
+    r"\bjax\.profiler\b|\bTraceAnnotation\b"
+    r"|\btime\.(?:perf_counter|time)(?:_ns)?\b"
+    r"|from\s+time\s+import\s+[^\n]*\b(?:perf_counter|time)(?:_ns)?\b")
+_HOST_TIMING_ALLOWED = ("src/repro/serving/telemetry.py",)
+
 
 def _scan(root: pathlib.Path = REPO) -> list:
     """All violations as (relpath, lineno, message) triples."""
@@ -94,6 +105,14 @@ def _scan(root: pathlib.Path = REPO) -> list:
                                 "through serving.telemetry.TraceRecorder "
                                 "(or tag '# lint: allow-print' if this "
                                 "is genuinely presentation code)"))
+                if (rel.startswith(_PRINT_SCOPE)
+                        and rel not in _HOST_TIMING_ALLOWED
+                        and _HOST_TIMING.search(line)):
+                    out.append((rel, n,
+                                "host timing in the serving stack outside "
+                                "serving.telemetry — use host_span / "
+                                "host_count, which stay off until "
+                                "host_tracing() turns them on"))
     return out
 
 
