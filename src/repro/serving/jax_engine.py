@@ -44,6 +44,7 @@ only ever builds chunked analytical pools.
 """
 from __future__ import annotations
 
+from functools import partial
 from typing import Dict, List, Optional, Sequence
 
 import jax
@@ -66,6 +67,64 @@ _METER_KEYS = ("joules", "idle_joules", "prefill_joules", "dispatch_joules",
                "m_dispatch_joules", "tokens", "m_tokens", "prefill_tokens")
 
 
+# platforms whose drain reads a row's value at per-entry indices with a
+# compare-and-select over the indexed axis; every other keeps the gather
+_SELECT_PLATFORMS = ("tpu",)
+
+
+def _lookup(platform: str) -> str:
+    """The lowering of the drain's row lookups on `platform`."""
+    return "select" if platform in _SELECT_PLATFORMS else "gather"
+
+
+def _platform() -> str:
+    """The platform the drain compiles for: the default backend, where
+    `drain_engines` puts each group."""
+    return jax.default_backend()
+
+
+def _select_rows(v, idx):
+    """`take_along_axis(v, idx, axis=1)` for (I, N) `v` and (I, M) `idx`
+    in [0, N), as a one-hot over N reduced so that it only selects: `any`
+    for bools, else a max over the value where hot and the dtype's least
+    value elsewhere (a float keeps -0.0, inf and nan).  The longer of N
+    and M is the minor axis of the (I, ., .) one-hot, so TPU lanes stay
+    full when the other is a handful of slots."""
+    n, m = v.shape[1], idx.shape[1]
+    cols = jnp.arange(n, dtype=idx.dtype)
+    if m >= n:
+        hot, vals, axis = idx[:, None, :] == cols[None, :, None], \
+            v[:, :, None], 1
+    else:
+        hot, vals, axis = idx[:, :, None] == cols[None, None, :], \
+            v[:, None, :], 2
+    if v.dtype == jnp.bool_:
+        return (hot & vals).any(axis)
+    fill = -jnp.inf if jnp.issubdtype(v.dtype, jnp.floating) \
+        else jnp.iinfo(v.dtype).min
+    return jnp.where(hot, vals, fill).max(axis)
+
+
+def _take_rows(v, idx, platform: str):
+    """`out[i, m] = v[i, idx[i, m]]`, lowered for the platform the drain
+    compiles for: a gather on the CPU, whose XLA makes an (I, N, M)
+    one-hot ~8x slower than the gather at N=64, and a lane-parallel select
+    on the TPU, where a gather serializes over its entries (4.5-7.6 ns an
+    entry in the drain on a v5e).  Both give the same bits."""
+    if _lookup(platform) == "select":
+        return _select_rows(v, idx)
+    return jnp.take_along_axis(v, idx, axis=1)
+
+
+def _rank_rows(cum, ranks, platform: str):
+    """Row-wise `searchsorted(cum[i], ranks[i])` (side left) of a
+    nondecreasing (I, S) `cum`, lowered like `_take_rows`: a binary search
+    on the CPU, on the TPU the count of `cum[i, s] < rank` over S, which
+    gathers nothing."""
+    method = "compare_all" if _lookup(platform) == "select" else "scan"
+    return jax.vmap(partial(jnp.searchsorted, method=method))(cum, ranks)
+
+
 def _bucket(n: int, floor: int = 8) -> int:
     """Round a ragged dim up to a power of two (>= floor) so stacked
     grids of nearby shapes reuse one compiled drain."""
@@ -76,8 +135,8 @@ def _bucket(n: int, floor: int = 8) -> int:
 # the compiled drain: one scenario = one (I, S, Q) pool; vmap adds axis 0
 # --------------------------------------------------------------------------
 
-def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
-               n_slots_pad: int) -> Dict[str, "jax.Array"]:
+def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
+               platform: str) -> Dict[str, "jax.Array"]:
     """One compiled drain over a row-concatenated batch of pools.
 
     Every piece of engine state is per-instance, so *many* pools — across
@@ -89,8 +148,11 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
     a distinct signature costs a ~2 s XLA build — an order of magnitude
     more than running the warmed program — so shape (S, Q, total I) is
     deliberately the only thing that forces a retrace, and rows pay no
-    padding for their neighbors' instance counts."""
+    padding for their neighbors' instance counts.  `platform` is the one
+    the drain compiles for, which picks the lowering of its row lookups
+    (`_take_rows`)."""
     S = n_slots_pad
+    take_rows = partial(_take_rows, platform=platform)
     evict = p["evict"]
     respect = p["respect"]
     I, Q = p["q_ready"].shape
@@ -131,22 +193,24 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         """Record one terminal/drain event per masked slot into the
         queue-indexed out arrays.  Event masks/values live in slot space
         (I, S); rather than scattering them to queue columns (XLA:CPU
-        lowers scatters — and (I, S, Q) one-hot reductions — to ~ms-scale
-        loops), every queue entry *gathers* from the slot recorded in
-        `q_slot` at its admission.  A gather lane is live only while
-        `slot_q` still points back at the entry (its slot has not been
-        recycled), which makes the stale-mapping check one (I, Q)
-        compare."""
+        lowers scatters to ~ms-scale loops), every queue entry reads the
+        slot recorded in `q_slot` at its admission through `_take_rows`:
+        a gather on the CPU, where an (I, S, Q) one-hot costs ~8x the
+        gather at S=64, and a select over S on the TPU, where the gather
+        serializes at 4.5-7.6 ns an entry and held ~89-97% of the drain's
+        device time (TPU v5e).  A lane is live only while `slot_q` still
+        points back at the entry (its slot has not been recycled), which
+        makes the stale-mapping check one (I, Q) compare."""
         sq = st["q_slot"]
 
         def g(v):                      # (I,S) slot values at each entry
-            return jnp.take_along_axis(jnp.broadcast_to(v, (I, S)), sq,
-                                       axis=1)
+            if jnp.ndim(v) == 2 and jnp.shape(v)[1] == S:
+                return take_rows(v, sq)
+            return jnp.broadcast_to(v, (I, Q))    # one value for all slots
 
         hit = g(mask) & (g(st["slot_q"]) == qidx)
         if kind is not None:
-            k = g(kind) if jnp.ndim(kind) == 2 else kind
-            st["out_kind"] = jnp.where(hit, k, st["out_kind"])
+            st["out_kind"] = jnp.where(hit, g(kind), st["out_kind"])
             st["out_time"] = jnp.where(hit, g(time_val), st["out_time"])
             st["out_step"] = jnp.where(hit, st["it"], st["out_step"])
             st["out_slot"] = jnp.where(hit, sq, st["out_slot"])
@@ -210,17 +274,17 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         adm_q = (qidx >= st["qpos"][:, None]) \
             & (qidx < (st["qpos"] + n_admit)[:, None])
         ranks = qidx - st["qpos"][:, None] + 1
-        slot_of_q = jax.vmap(jnp.searchsorted)(cum_free, ranks).astype(i32)
+        slot_of_q = _rank_rows(cum_free, ranks, platform).astype(i32)
         st["q_slot"] = jnp.where(adm_q, jnp.clip(slot_of_q, 0, S - 1),
                                  st["q_slot"])
-        gather = lambda a: jnp.take_along_axis(a, src, axis=1)  # noqa: E731
-        a_plen = gather(p["q_plen"])
-        a_pd = gather(p["q_pdone"])
+        at_src = lambda a: take_rows(a, src)  # noqa: E731
+        a_plen = at_src(p["q_plen"])
+        a_pd = at_src(p["q_pdone"])
         st["active"] = st["active"] | adm
         st["pos"] = jnp.where(adm, a_plen, st["pos"])
-        st["max_new"] = jnp.where(adm, gather(p["q_maxnew"]), st["max_new"])
-        st["ready_ts"] = jnp.where(adm, gather(p["q_ready"]), st["ready_ts"])
-        st["esc"] = jnp.where(adm, gather(p["q_esc"]), st["esc"])
+        st["max_new"] = jnp.where(adm, at_src(p["q_maxnew"]), st["max_new"])
+        st["ready_ts"] = jnp.where(adm, at_src(p["q_ready"]), st["ready_ts"])
+        st["esc"] = jnp.where(adm, at_src(p["q_esc"]), st["esc"])
         st["slot_q"] = jnp.where(adm, src, st["slot_q"])
         st["gen_count"] = jnp.where(adm, jnp.where(a_pd, 1, 0),
                                     st["gen_count"])
@@ -403,18 +467,16 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
         key = jnp.where(pend, st["ready_ts"], jnp.inf)
         order = jnp.argsort(key, axis=1, stable=True)
         inv = jnp.argsort(order, axis=1)
-        pl_srt = jnp.take_along_axis(
-            jnp.where(pend, st["prefill_left"], 0), order, axis=1)
+        pl_srt = take_rows(jnp.where(pend, st["prefill_left"], 0), order)
         cum_excl = jnp.cumsum(pl_srt, axis=1) - pl_srt
         take_srt = jnp.minimum(pl_srt,
                                jnp.maximum(p["chunk"][:, None] - cum_excl, 0))
         st, sim, t_after_srt = charge_prefill_span(
             st, take_srt, jnp.zeros((I, S)), sim)
         drained_srt = (take_srt > 0) & (take_srt == pl_srt)
-        unsort = lambda a: jnp.take_along_axis(a, inv, axis=1)  # noqa: E731
-        take = unsort(take_srt)
-        drained = unsort(drained_srt)
-        t_after = unsort(t_after_srt)
+        take = take_rows(take_srt, inv)
+        drained = take_rows(drained_srt, inv)
+        t_after = take_rows(t_after_srt, inv)
         st["prefill_left"] = st["prefill_left"] - take
         st = emit(st, drained, _EV_HANDOFF, t_after, ngen=1, first=t_after)
         st["active"] = st["active"] & ~drained
@@ -470,8 +532,9 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str,
     return jax.lax.while_loop(cond, body, st0)
 
 
-# one compiled program per (phase, n_slots_pad) and argument shapes
-_drain = jax.jit(_drain_one, static_argnames=("phase", "n_slots_pad"))
+# one compiled program per (phase, n_slots_pad, platform) and argument shapes
+_drain = jax.jit(_drain_one,
+                 static_argnames=("phase", "n_slots_pad", "platform"))
 
 
 # --------------------------------------------------------------------------
@@ -521,26 +584,31 @@ def drain_engines(engines: Sequence["JaxPoolEngine"], *,
         if dims is None:
             dims = (1, _bucket(S), _bucket(Q))
         groups.setdefault((eng.phase, *dims), []).append(eng)
+    platform = _platform()
+    lookup = _lookup(platform)
     with enable_x64():
         for (phase, i_floor, s_pad, q_pad), engs in groups.items():
             rows = [packed[id(e)] for e in engs]
             i_tot = sum(e.instances for e in engs)
             i_pad = _bucket(max(i_tot, i_floor))
             with host_span("drain.group", phase=phase, rows=i_tot,
-                           rows_padded=i_pad, s_pad=s_pad, q_pad=q_pad):
+                           rows_padded=i_pad, s_pad=s_pad, q_pad=q_pad,
+                           lookup=lookup):
                 with host_span("drain.stack"):
                     merged = _stack(rows, i_pad, q_pad)
                 with host_span("drain.launch"):
                     out = _drain(jax.device_put(merged), phase=phase,
-                                 n_slots_pad=s_pad)
+                                 n_slots_pad=s_pad, platform=platform)
                 with host_span("drain.wait"):
                     out = jax.block_until_ready(out)
                 with host_span("drain.fetch"):
                     out = {k: np.asarray(v) for k, v in out.items()}
                 # iteration-weighted real and padded queue entries: the
-                # loop gathers over every (i_pad, q_pad) entry each step
+                # loop reads every (i_pad, q_pad) entry each step
                 it = int(out["it"])
                 host_count("drain.groups", 1)
+                if lookup == "select":
+                    host_count("drain.select_groups", 1)
                 host_count("drain.iters", it)
                 host_count("drain.entry_iters",
                            it * sum(int(r["qlen"].sum()) for r in rows))

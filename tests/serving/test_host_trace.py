@@ -4,14 +4,16 @@ every layer of the fleet path with its parent, its drain counters equal
 what the drain returned, and its reports do not move."""
 import json
 
+import numpy as np
 import pytest
 
 from repro.core.modelspec import LLAMA31_70B
 from repro.core.profiles import H100_LLAMA70B
 from repro.core.topospec import TopologySpec
 from repro.core.workloads import AZURE
-from repro.serving import prepare_spec, run_fleet_grid, telemetry
-from repro.serving.jax_engine import JaxPoolEngine, _bucket
+from repro.serving import (Request, jax_engine, prepare_spec, run_fleet_grid,
+                           telemetry)
+from repro.serving.jax_engine import JaxPoolEngine, _bucket, drain_engines
 
 # span -> the span it opens under in a `run_fleet_grid` call
 PARENTS = {
@@ -164,3 +166,31 @@ def test_drain_counters_equal_what_the_drain_returned(traced):
 def test_grid_reports_are_bit_identical_with_the_recorder_off(traced):
     _, reports_on, _ = traced
     assert _grid() == reports_on
+
+
+@pytest.mark.parametrize("lookup", ["gather", "select"])
+def test_drain_groups_name_their_lookup(lookup, monkeypatch):
+    """Each `drain.group` span names the lowering of the drain's row
+    lookups, and `drain.select_groups` counts the groups drained with the
+    select: all of them where it is forced as on a TPU, none on the CPU's
+    own gather."""
+    if lookup == "select":                   # compile as for a TPU
+        monkeypatch.setattr(jax_engine, "_platform", lambda: "tpu")
+    engines = []
+    for phase, n_slots in (("decode", 2), ("prefill", 3)):
+        eng = JaxPoolEngine(instances=2, window=4096, n_slots=n_slots,
+                            profile=H100_LLAMA70B, phase=phase,
+                            streamed_params=LLAMA31_70B.streamed_params,
+                            prefill_chunk=256, respect_arrival=True)
+        for i in range(6):
+            eng.submit(Request(rid=i, max_new_tokens=4, arrival_time=0.01 * i,
+                               prompt=np.zeros(300, np.int64)), i % 2)
+        eng.sort_queues()
+        engines.append(eng)
+    with telemetry.host_tracing() as rec:
+        drain_engines(engines)
+    groups = [s[4] for s in rec.spans if s[0] == "drain.group"]
+    assert len(groups) == rec.counters["drain.groups"] == 2
+    assert [g["lookup"] for g in groups] == [lookup] * 2
+    assert rec.counters.get("drain.select_groups", 0) \
+        == (2 if lookup == "select" else 0)

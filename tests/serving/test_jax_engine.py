@@ -13,16 +13,20 @@ bit-exact parity contract against the scalar engines untouched
 (tests/serving/test_soa_parity.py).
 """
 import copy
+from functools import partial
 
 import numpy as np
 import pytest
 
 jax = pytest.importorskip("jax")
+import jax.numpy as jnp  # noqa: E402
 
 from repro.core.modelspec import LLAMA31_70B
 from repro.core.profiles import B200_LLAMA70B, H100_LLAMA70B
 from repro.core.workloads import AZURE
-from repro.serving import BatchedPoolEngine, Request
+from repro.models.compat import enable_x64
+from repro.serving import BatchedPoolEngine, Request, jax_engine
+from repro.serving.engine import _NEVER
 from repro.serving.jax_engine import JaxPoolEngine, drain_engines
 
 STREAMED = LLAMA31_70B.streamed_params
@@ -39,10 +43,12 @@ def _req(rid, plen, out, t=0.0, pred=None, esc=None, pdone=False):
     return r
 
 
-def _mk(cls, reqs_by_inst, *, profile=H100_LLAMA70B, **kw):
+def _mk(cls, reqs_by_inst, *, profile=H100_LLAMA70B, measure=None, **kw):
     eng = cls(instances=len(reqs_by_inst), profile=profile,
               streamed_params=STREAMED, rng_seed=11, name="p",
               respect_arrival=True, **kw)
+    if measure is not None:               # (t0, t1) measurement window
+        eng.bank.measure_t0, eng.bank.measure_t1 = measure
     for j, reqs in enumerate(reqs_by_inst):
         for r in reqs:
             eng.submit(copy.copy(r), j)
@@ -101,21 +107,77 @@ def _assert_parity(ref, jx, rtol=1e-9):
                         rb.ready_time, rel=rtol, abs=1e-12), (field, ra.rid)
 
 
-def test_jax_parity_admission_and_chunked_interleave():
+# parity scenarios: each is a list of (per-instance streams, engine kw),
+# one entry per engine of one `drain_engines` call
+
+def _case_interleave():
     rng = np.random.default_rng(3)
     reqs = [[_req(i + 100 * j, int(rng.integers(1, 3000)),
                   int(rng.integers(1, 150)), t=0.04 * i)
              for i in range(40)] for j in range(3)]
-    _assert_parity(*_run_both(reqs, window=4096, n_slots=4,
-                              prefill_chunk=256))
+    return [(reqs, dict(window=4096, n_slots=4, prefill_chunk=256))]
 
 
-def test_jax_parity_window_ceiling_overflow_chain():
+def _case_overflow_chain():
     reqs = [[_req(j * 50, 100, 5000)] +
             [_req(j * 50 + 1 + i, 40, 30, t=0.01 * i) for i in range(12)]
             for j in range(2)]
-    ref, jx = _run_both(reqs, window=256, n_slots=2, prefill_chunk=128,
-                        evict_on_overflow=True)
+    return [(reqs, dict(window=256, n_slots=2, prefill_chunk=128,
+                        evict_on_overflow=True))]
+
+
+def _case_escalation_in_window():
+    # the measurement window opens mid-run
+    reqs = [[_req(i, 64, 400, esc=6, t=0.05 * i) for i in range(5)]
+            for _ in range(2)]
+    return [(reqs, dict(window=8192, n_slots=2, prefill_chunk=128,
+                        measure=(0.1, 1e9)))]
+
+
+def _case_prefill_fifo():
+    rng = np.random.default_rng(9)
+    reqs = [[_req(i + 30 * j, int(rng.integers(64, 7000)), 1, t=0.03 * i)
+             for i in range(25)] for j in range(2)]
+    return [(reqs, dict(window=8192, n_slots=4, prefill_chunk=512,
+                        phase="prefill"))]
+
+
+def _case_ragged():
+    """Engines with different instance counts, slot counts, queue
+    lengths, profiles and phases."""
+    rng = np.random.default_rng(17)
+
+    def mkstreams(n_inst, n, stride):
+        return [[_req(1000 * stride + i + 100 * j,
+                      int(rng.integers(1, 2000)),
+                      int(rng.integers(1, 80)), t=0.05 * i)
+                 for i in range(n)] for j in range(n_inst)]
+
+    return [(mkstreams(1, 30, 0),
+             dict(window=4096, n_slots=4, prefill_chunk=256)),
+            (mkstreams(3, 7, 1),
+             dict(window=2048, n_slots=2, prefill_chunk=128,
+                  evict_on_overflow=True, profile=B200_LLAMA70B)),
+            (mkstreams(2, 18, 2),
+             dict(window=8192, n_slots=3, prefill_chunk=512,
+                  phase="prefill"))]
+
+
+CASES = {"interleave": _case_interleave,
+         "overflow_chain": _case_overflow_chain,
+         "escalation_in_window": _case_escalation_in_window,
+         "prefill_fifo": _case_prefill_fifo,
+         "ragged": _case_ragged}
+
+
+def test_jax_parity_admission_and_chunked_interleave():
+    (reqs, kw), = _case_interleave()
+    _assert_parity(*_run_both(reqs, **kw))
+
+
+def test_jax_parity_window_ceiling_overflow_chain():
+    (reqs, kw), = _case_overflow_chain()
+    ref, jx = _run_both(reqs, **kw)
     _assert_parity(ref, jx)
     assert all(len(o) > 0 for o in jx.overflowed)
 
@@ -123,26 +185,15 @@ def test_jax_parity_window_ceiling_overflow_chain():
 def test_jax_parity_escalation_backout_in_window():
     """Escalations *inside* the measurement window: the windowed m_*
     counters must back out exactly what the numpy oracle backs out."""
-    reqs = [[_req(i, 64, 400, esc=6, t=0.05 * i) for i in range(5)]
-            for _ in range(2)]
-    ref = _mk(BatchedPoolEngine, reqs, window=8192, n_slots=2,
-              prefill_chunk=128)
-    jx = _mk(JaxPoolEngine, reqs, window=8192, n_slots=2,
-             prefill_chunk=128)
-    for e in (ref, jx):                   # window opens mid-run
-        e.bank.measure_t0, e.bank.measure_t1 = 0.1, 1e9
-    ref.run_until_drained(max_iters=200_000)
-    jx.run_until_drained(max_iters=200_000)
+    (reqs, kw), = _case_escalation_in_window()
+    ref, jx = _run_both(reqs, **kw)
     _assert_parity(ref, jx)
     assert int(jx.n_escalated.sum()) == 10
 
 
 def test_jax_parity_prefill_phase_fifo():
-    rng = np.random.default_rng(9)
-    reqs = [[_req(i + 30 * j, int(rng.integers(64, 7000)), 1, t=0.03 * i)
-             for i in range(25)] for j in range(2)]
-    ref, jx = _run_both(reqs, window=8192, n_slots=4, prefill_chunk=512,
-                        phase="prefill")
+    (reqs, kw), = _case_prefill_fifo()
+    ref, jx = _run_both(reqs, **kw)
     _assert_parity(ref, jx)
     assert all(len(h) > 0 for h in jx.handoff)
     # handoff first tokens are live LCG values, not placeholders
@@ -168,37 +219,114 @@ def test_jax_unchunked_decode_unsupported():
                       streamed_params=STREAMED, prefill_chunk=0)
 
 
+def _drain_case(specs):
+    """The numpy oracle's engines, each drained alone, and the compiled
+    engines of one `drain_engines` call over the same specs, with each
+    compiled engine's staged outputs (every out array, meter row and
+    `it`) before it is finalized."""
+    refs = [_mk(BatchedPoolEngine, s, **kw) for s, kw in specs]
+    jxs = [_mk(JaxPoolEngine, s, **kw) for s, kw in specs]
+    for e in refs:
+        e.run_until_drained(max_iters=200_000)
+    drain_engines(jxs, max_iters=200_000)
+    staged = [dict(e._staged) for e in jxs]
+    for e in jxs:
+        e.run_until_drained(max_iters=200_000)   # consumes staged result
+    return refs, jxs, staged
+
+
 def test_drain_engines_ragged_batch():
     """One `drain_engines` call over engines with different instance
     counts, slot counts, queue lengths, profiles and phases must equal
     each engine drained alone by the numpy oracle — the padding masks may
     not leak work into (or out of) dead rows."""
-    rng = np.random.default_rng(17)
-
-    def mkstreams(n_inst, n, stride):
-        return [[_req(1000 * stride + i + 100 * j,
-                      int(rng.integers(1, 2000)),
-                      int(rng.integers(1, 80)), t=0.05 * i)
-                 for i in range(n)] for j in range(n_inst)]
-
-    cfgs = [dict(window=4096, n_slots=4, prefill_chunk=256),
-            dict(window=2048, n_slots=2, prefill_chunk=128,
-                 evict_on_overflow=True),
-            dict(window=8192, n_slots=3, prefill_chunk=512,
-                 phase="prefill")]
-    profiles = [H100_LLAMA70B, B200_LLAMA70B, H100_LLAMA70B]
-    streams = [mkstreams(1, 30, 0), mkstreams(3, 7, 1), mkstreams(2, 18, 2)]
-    refs = [_mk(BatchedPoolEngine, s, profile=p, **c)
-            for s, p, c in zip(streams, profiles, cfgs)]
-    jxs = [_mk(JaxPoolEngine, s, profile=p, **c)
-           for s, p, c in zip(streams, profiles, cfgs)]
-    for e in refs:
-        e.run_until_drained(max_iters=200_000)
-    drain_engines(jxs, max_iters=200_000)
-    for e in jxs:
-        e.run_until_drained(max_iters=200_000)   # consumes staged result
+    refs, jxs, _ = _drain_case(_case_ragged())
     for ref, jx in zip(refs, jxs):
         _assert_parity(ref, jx)
+
+
+@pytest.mark.parametrize("case", sorted(CASES))
+def test_select_lowering_drains_bit_identically(case, monkeypatch):
+    """The TPU's lowering of the drain's row lookups (a select over the
+    indexed axis), compiled here on the CPU as if for a TPU, stages the
+    very bits the gather lowering stages, and both still match the numpy
+    oracle."""
+    refs, jxs, gathered = _drain_case(CASES[case]())
+    monkeypatch.setattr(jax_engine, "_platform", lambda: "tpu")
+    _, sel, selected = _drain_case(CASES[case]())
+    for ref, jx, js in zip(refs, jxs, sel):
+        _assert_parity(ref, jx)
+        _assert_parity(ref, js)
+    assert int(selected[0]["it"]) > 0
+    for a, b in zip(gathered, selected):
+        assert a.keys() == b.keys()
+        for k in a:
+            assert a[k].dtype == b[k].dtype and a[k].shape == b[k].shape, k
+            assert a[k].tobytes() == b[k].tobytes(), k
+
+
+# --- the two lowerings of a row lookup, value by value -------------------
+
+LOOKUP_SHAPES = [(32, 64, 512), (256, 8, 256), (16, 8, 64), (7, 5, 13)]
+
+
+def _lookup_values(rng, kind, shape):
+    """Random values of `kind` with its edge values mixed in: -0.0, +-inf
+    and nan for f64; the escalation sentinel and the int32 extremes."""
+    if kind == "bool":
+        return rng.random(shape) < 0.5
+    hot = rng.random(shape) < 0.3
+    if kind == "f64":
+        v = rng.standard_normal(shape) * 1e3
+        edge = np.array([-0.0, 0.0, np.inf, -np.inf, np.nan])
+    else:
+        info = np.iinfo(np.int32)
+        v = rng.integers(info.min, info.max, shape, dtype=np.int32,
+                         endpoint=True)
+        edge = np.array([_NEVER, info.min, info.max, 0, -1], np.int32)
+    v[hot] = rng.choice(edge, int(hot.sum()))
+    v[:, :len(edge)] = edge              # each row's first five
+    return v
+
+
+@pytest.mark.parametrize("shape", LOOKUP_SHAPES,
+                         ids=["x".join(map(str, s)) for s in LOOKUP_SHAPES])
+@pytest.mark.parametrize("kind", ["f64", "i32", "bool", "rank"])
+def test_select_lookup_matches_gather_bit_for_bit(kind, shape):
+    """The select lowering against the gather in each direction the
+    drain reads — emit's (I, S) slots at (I, Q) entries, admit's (I, Q)
+    queue at (I, S) slots, the prefill sort's (I, S) at (I, S) — and the
+    compare-form inverse map against `vmap(searchsorted)`, ranks past the
+    last free slot and rows with no free slot included."""
+    I, S, Q = shape
+    rng = np.random.default_rng(I * S * Q)
+    with enable_x64():
+        if kind == "rank":
+            free = rng.random((I, S)) < 0.5
+            free[::3] = False                     # rows with no free slot
+            cum = np.cumsum(free, axis=1, dtype=np.int32)
+            qpos = rng.integers(0, Q, I)
+            ranks = (np.arange(Q)[None, :] - qpos[:, None] + 1
+                     ).astype(np.int32)
+            assert (ranks > cum[:, -1:]).any() and (ranks <= 0).any()
+            got = np.asarray(jax.jit(partial(
+                jax_engine._rank_rows, platform="tpu"))(cum, ranks))
+            want = np.asarray(jax.jit(jax.vmap(jnp.searchsorted))(cum, ranks))
+            assert got.dtype == want.dtype
+            np.testing.assert_array_equal(got, want)
+            return
+        select = jax.jit(partial(jax_engine._take_rows, platform="tpu"))
+        gather = jax.jit(partial(jax_engine._take_rows, platform="cpu"))
+        for n, m in ((S, Q), (Q, S), (S, S)):
+            v = _lookup_values(rng, kind, (I, n))
+            idx = rng.integers(0, n, (I, m)).astype(np.int32)
+            idx[:, :5] = np.arange(5)            # ... are looked up
+            got, want = np.asarray(select(v, idx)), np.asarray(gather(v, idx))
+            assert got.dtype == want.dtype and got.shape == (I, m)
+            assert got.tobytes() == want.tobytes(), (n, m)
+            if kind == "f64":
+                assert np.isnan(got[:, 4]).all()
+                assert np.signbit(got[:, 0]).all() and (got[:, 0] == 0).all()
 
 
 def test_jax_fleet_matches_numpy_fleet_seed_numbers():
