@@ -6,7 +6,12 @@ are the benchmark's own files and the triples the program was given:
 
   * the configuration (`bench/configs/<config>.json`): the model, chip and
     power numbers of its `build` entries, and `profile` (the constants that
-    turn them into a decode roofline and a KV capacity);
+    turn them into a decode roofline and a KV and state capacity).  The
+    model entry may state recurrent state beside attention:
+    `attn_layer_fraction` (share of `n_layers` that hold a KV cache,
+    default 1), `n_state_layers` (layers that carry recurrent state,
+    default 0) and `state_bytes_per_layer` (one such layer's state for one
+    sequence, whole layer before the TP split, default 0);
   * the cell's stated sizing (`bench/sizing/<cell>.json`): every pool's
     role, name, window, instance count, admission bound and overflow
     destination, in the order the pools drain;
@@ -25,14 +30,18 @@ What a run does, as this module computes it:
     admit    queued requests whose ready time has come take the lowest free
              slots, in queue order (queues sorted by ready time);
     decode   every slot whose prompt is done emits a token: the step lasts
-             tau = (W + H0 * (mean context / L) * n) ms at n such slots and
+             tau = (W + (S + H0 * (mean context / L)) * n) ms at n such
+             slots, S the read and write of one sequence's state, and
              draws the logistic power P(n); a slot finishes when it has its
              output, and at the window's ceiling it finishes too, or, in a
              pool that overflows, is evicted to the next pool (its decode
              tokens taken back off the meters);
     prefill  512 prompt tokens a step (the chunk), lowest slot first,
              hidden behind the step's decode time where they fit; a prompt
-             that completes gives its first token then;
+             that completes gives its first token then.  A token costs
+             2 * active parameters FLOPs for every binding: neither
+             attention's quadratic term nor the state layers' own scan is
+             charged, and multi-token-prediction heads are not modelled;
   overflow   evicted requests enter the next pool in order of eviction time,
              balanced on that pool's assigned work, and re-prefill there;
   meters     every charge also counts in the `m_*` meters where it falls in
@@ -72,44 +81,73 @@ def load_sizing(cell_name: str) -> dict:
 
 
 def deployment(config: dict, sizing: dict, traffic: dict) -> dict:
-    """Everything one run needs, from the benchmark's files alone."""
+    """Everything one run needs, from the benchmark's files alone.
+
+    kappa, the KV bytes per token per GPU, is ceil(kv heads / tp) heads (at
+    least one) of K and V in the `attn_layer_fraction` of the layers that
+    hold a KV cache, with the paged cache's overhead.  sigma, the state
+    bytes per sequence per GPU, is `n_state_layers` layers of
+    `state_bytes_per_layer` divided over the TP ranks; it is one fixed
+    slab a slot, so the paged overhead does not apply.  A pool of window w
+    holds floor(budget / (kappa w + sigma)) slots, at least one; one slot
+    where the weights fill the memory.  A decode step reads and writes every sequence's state
+    once: S = 2 sigma at the KV-scan efficiency, per sequence a step."""
     kw = {e["name"]: e.get("kwargs", {}) for e in config["build"]}
     model, chip, power = kw["model"], kw["chip"], kw["power"]
     c = config["profile"]
     tp = c["tp"]
-    # KV bytes per token per GPU: ceil(kv heads / tp) heads (at least one)
-    # of K and V in every layer, with the paged cache's overhead
     heads = float(max(math.ceil(model["n_kv_heads"] / tp), 1))
     kappa = 2.0 * heads * model["head_dim"] * model["dtype_bytes"] \
-        * model["n_layers"] * 1.0 * c["kv_overhead"]
+        * model["n_layers"] * model.get("attn_layer_fraction", 1.0) \
+        * c["kv_overhead"]
+    sigma = model.get("n_state_layers", 0) \
+        * model.get("state_bytes_per_layer", 0.0) / tp
+    if kappa == 0 and sigma == 0:
+        raise ValueError(
+            f"model {model.get('name')!r} holds neither a KV cache nor "
+            "recurrent state (attn_layer_fraction * n_layers and "
+            "n_state_layers * state_bytes_per_layer are both 0): its "
+            "binding would have no concurrency ceiling")
     weights_gpu = model["n_params"] * model["dtype_bytes"] / tp
     budget = chip["vram_bytes"] * (1.0 - c["vram_reserve_frac"]) \
         - weights_gpu
-    capacity = max(budget, 0.0) / kappa if budget > 0 else 1.0
+
+    def n_slots(window: float) -> int:
+        if budget <= 0:
+            return 1
+        return max(int(math.floor(budget / (kappa * window + sigma))), 1)
+
     active = model.get("n_active_params") or model["n_params"]
     streamed = active if active < model["n_params"] else model["n_params"]
     bw = chip["mem_bw_Bps"]
     w_ms = streamed * model["dtype_bytes"] / tp \
         / (c["weight_stream_efficiency"] * bw) * 1e3
     h0_ms = kappa * c["l_calib"] / (c["kv_scan_efficiency"] * bw) * 1e3
+    s_ms = 2.0 * sigma / (c["kv_scan_efficiency"] * bw) * 1e3
     w_ms += c["dispatch_ms"]
     pools = []
     for p in sizing["pools"]:
         bound = p["admit_up_to"]
         pools.append(dict(
             p, admit_up_to=math.inf if bound is None else float(bound),
-            n_slots=max(int(math.floor(capacity / float(p["window"]))), 1)))
+            n_slots=n_slots(float(p["window"]))))
     lens = sampler.length_pool(traffic["sample"],
                                traffic["workload"]["kwargs"])
     return dict(
         pools=pools, max_window=max(p["window"] for p in pools),
         predicted_output=int(round(float(lens[1].mean()))),
-        w_ms=w_ms, h0_ms=h0_ms, l_calib=float(c["l_calib"]),
+        w_ms=w_ms, h0_ms=h0_ms, s_ms=s_ms, l_calib=float(c["l_calib"]),
         dispatch_s=c["dispatch_ms"] * 1e-3,
         p_idle=float(power["p_idle_w"]), p_nom=float(power["p_nom_w"]),
         k=float(power["k"]), x0=float(power["x0"]),
         chunk=config["prefill_chunk"], prefill_flops_per_token=2.0 * streamed,
         prefill_flops_per_s=tp * chip["peak_bf16_flops"] * c["prefill_mfu"])
+
+
+def step_ms(d: dict, n: int, ctx: float) -> float:
+    """A decode step's length at n decoding slots of mean context ctx:
+    weights once, each sequence's state and KV scan once."""
+    return d["w_ms"] + (d["s_ms"] + d["h0_ms"] * (ctx / d["l_calib"])) * n
 
 
 class Req:
@@ -213,8 +251,7 @@ class Instance:
             if dec:
                 n = len(dec)
                 ctx = sum(slots[s][1] for s in dec) / n
-                tau = (d["w_ms"] + d["h0_ms"] * (ctx / d["l_calib"]) * n) \
-                    * 1e-3
+                tau = step_ms(d, n, ctx) * 1e-3
                 power = self._power(n)
                 inside = self.t0 <= self.t + 0.5 * tau <= self.t1
                 e = power * tau

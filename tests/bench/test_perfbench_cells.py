@@ -4,6 +4,7 @@ the two agree on what the files state; the plain reference answers as the
 program's numpy engine does; a missing file fails loudly; every per-layer
 metric has its reader."""
 import importlib
+import math
 import os
 import re
 import sys
@@ -52,6 +53,15 @@ def test_cell_builds_under_both_roots(name):
     assert prof.roofline.h0_ms == pytest.approx(d["h0_ms"], rel=1e-12)
     assert prof.power_model.p_idle_w == d["p_idle"]
     assert prof.tp == cell["config_data"]["profile"]["tp"]
+    # the decode step itself, whatever terms either side splits it into
+    roles = {p.role: p.profile for p in prog["spec"].pools}
+    for p in d["pools"]:
+        roofline = roles[p["role"]].roofline
+        for n in sorted({1, math.ceil(p["n_slots"] / 2), p["n_slots"]}):
+            for ctx in (512, p["window"] / 2, p["window"] - 1):
+                assert float(roofline.tau_ms(n, ctx)) == pytest.approx(
+                    plainref.step_ms(d, n, ctx), rel=1e-12), (p["role"], n,
+                                                               ctx)
 
 
 @pytest.mark.parametrize("name", SIZED)
