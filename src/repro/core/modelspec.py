@@ -22,9 +22,11 @@ class ModelSpec:
     dtype_bytes: float = 2.0        # fp16/bf16 by default; 1.0 for fp8
     n_active_params: Optional[float] = None   # MoE: active params / token
     # Attention-free / hybrid geometry: recurrent state bytes per sequence
-    # per layer (replaces KV growth; O(1) in context length).
+    # per layer, whole layer before the TP split (replaces KV growth; O(1)
+    # in context length), held by `n_state_layers` layers.
     state_bytes_per_layer: float = 0.0
     attn_layer_fraction: float = 1.0  # hybrid: fraction of layers with KV
+    n_state_layers: int = 0
 
     @property
     def is_moe(self) -> bool:
@@ -60,6 +62,12 @@ class ModelSpec:
             heads = float(self.n_kv_heads)
         per_layer = 2.0 * heads * self.head_dim * self.dtype_bytes
         return per_layer * self.n_layers * self.attn_layer_fraction * overhead
+
+    def state_bytes_per_seq(self, *, tp: int = 1) -> float:
+        """sigma: recurrent-state bytes of one sequence per GPU, the state
+        layers divided over the TP ranks.  One fixed slab a sequence, so
+        the paged-KV overhead does not apply."""
+        return self.n_state_layers * self.state_bytes_per_layer / tp
 
 
 # --- The paper's own models (Table 2 / §4) ------------------------------

@@ -15,13 +15,13 @@ from .hardware import ChipSpec
 from .modelspec import ModelSpec
 from .power import PowerModel
 from .profiles import BaseProfile, computed_profile
-from .roofline import DecodeRoofline
 
 
 def with_dispatch_floor(profile: BaseProfile,
                         dispatch_ms: float) -> BaseProfile:
     """`profile` with an expert all-to-all dispatch cost added to the
-    per-iteration latency floor: tau(n, L) = (W + dispatch) + H(L) n.
+    per-iteration latency floor: tau(n, L) = (W + dispatch) + (S + H(L)) n,
+    every other roofline term kept.
 
     The floor is paid every decode iteration regardless of batch — exactly
     the mechanism that collapses the paper's 5.1x MoE upper bound toward
@@ -34,9 +34,8 @@ def with_dispatch_floor(profile: BaseProfile,
         return profile
     rl = profile.roofline
     return dataclasses.replace(
-        profile, roofline=DecodeRoofline(w_ms=rl.w_ms + dispatch_ms,
-                                         h0_ms=rl.h0_ms,
-                                         l_calib=rl.l_calib))
+        profile, roofline=dataclasses.replace(rl,
+                                              w_ms=rl.w_ms + dispatch_ms))
 
 
 def moe_profile(model: ModelSpec, chip: ChipSpec,

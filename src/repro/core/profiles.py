@@ -2,8 +2,8 @@
 
 A profile bundles, for one (model, accelerator, TP) deployment:
   * the logistic power model P(b)             (Eq. 1)
-  * the decode roofline tau(n, L) = W + H(L)n (§2.2)
-  * the KV token capacity -> n_max(window)    (Eq. 3)
+  * the decode roofline tau(n, L) = W + (S + H(L))n (§2.2)
+  * the KV and state budget -> n_max(window)   (Eq. 3)
 
 `ManualProfile` carries calibrated constants (the paper's HIGH-quality H100
 profile, and the Table-1 B200 projection).  `ComputedProfile` derives the same
@@ -15,8 +15,6 @@ from __future__ import annotations
 import dataclasses
 import math
 from typing import Optional, Protocol, runtime_checkable
-
-import numpy as np
 
 from .hardware import B200, GB200, H100, H200, TPU_V5E, ChipSpec
 from .modelspec import LLAMA31_70B, ModelSpec
@@ -46,14 +44,41 @@ class BaseProfile:
     chip: ChipSpec
     power_model: PowerModel
     roofline: DecodeRoofline
-    kv_token_capacity: float     # tokens of KV the cache budget holds (per GPU)
+    # per GPU, the memory the KV cache and recurrent state may fill, in
+    # units of `kv_bytes_per_token`: bytes for a computed profile, tokens of
+    # KV for a calibrated one (which leaves kappa at 1); <= 0 where the
+    # weights fill VRAM
+    kv_budget: float
     tp: int = 8
-    weights_exceed_vram: bool = False
+    kv_bytes_per_token: float = 1.0    # kappa
+    state_bytes_per_seq: float = 0.0   # sigma: one sequence's state slab
+
+    @property
+    def weights_exceed_vram(self) -> bool:
+        return self.kv_budget <= 0
+
+    @property
+    def kv_token_capacity(self) -> float:
+        """Tokens of KV the budget holds (1 where the weights fill VRAM)."""
+        if self.weights_exceed_vram:
+            return 1.0
+        if self.kv_bytes_per_token <= 0:
+            return math.inf
+        return self.kv_budget / self.kv_bytes_per_token
 
     def n_max(self, window: float) -> int:
-        """Eq. 3: concurrency ceiling at serving context window `window`."""
-        n = int(math.floor(self.kv_token_capacity / float(window)))
-        return max(n, 1)  # paper clamps to 1 (405B / DeepSeek rows)
+        """Eq. 3 with recurrent state: floor(budget / (kappa W + sigma))
+        sequences at serving window `window`, at least 1; 1 where the
+        weights fill VRAM (the paper's 405B / DeepSeek rows)."""
+        if self.weights_exceed_vram:
+            return 1
+        per_seq = self.kv_bytes_per_token * float(window) \
+            + self.state_bytes_per_seq
+        if per_seq <= 0:
+            raise ValueError(
+                f"profile {self.name!r} holds neither a KV cache nor "
+                "recurrent state: it has no concurrency ceiling")
+        return max(int(math.floor(self.kv_budget / per_seq)), 1)
 
     def power_w(self, n: float) -> float:
         return float(self.power_model.power_w(n))
@@ -97,20 +122,18 @@ def computed_profile(model: ModelSpec, chip: ChipSpec,
     budget = chip.vram_bytes * (1.0 - vram_reserve_frac) - weight_bytes_per_gpu
     kappa = model.kv_bytes_per_token(tp=tp, kv_sharded=kv_sharded,
                                      overhead=kv_overhead)
-    exceeds = budget <= 0
-    capacity = max(budget, 0.0) / kappa if kappa > 0 else np.inf
-    if exceeds:
-        capacity = 1.0  # clamp: paper reports n_max = 1 for over-VRAM models
+    sigma = model.state_bytes_per_seq(tp=tp)
     # Weight streaming uses *active* bytes (MoE §3.2 override; upper bound —
     # dispatch overhead excluded, see core.moe for the sensitivity analysis).
     roofline = DecodeRoofline.from_first_principles(
         weight_bytes_per_gpu=model.weight_bytes(active_only=True) / tp,
         kv_bytes_per_token_per_gpu=kappa if model.n_kv_heads else 1e-9,
+        state_bytes_per_seq_per_gpu=sigma,
         mem_bw_Bps=chip.mem_bw_Bps, l_calib=l_calib)
     return BaseProfile(name=name or f"{model.name}@{chip.name}(TP{tp})",
                        chip=chip, power_model=power_model, roofline=roofline,
-                       kv_token_capacity=capacity, tp=tp,
-                       weights_exceed_vram=exceeds)
+                       kv_budget=budget, tp=tp, kv_bytes_per_token=kappa,
+                       state_bytes_per_seq=sigma)
 
 
 # --- Calibrated headline profiles (paper §2.1 / Table 1) -----------------
@@ -122,7 +145,7 @@ H100_LLAMA70B = ManualProfile(
     name="Llama-3.1-70B@H100-SXM5(TP8,calibrated)",
     chip=H100, power_model=H100_POWER,
     roofline=DecodeRoofline(w_ms=6.72, h0_ms=0.139, l_calib=8192),
-    kv_token_capacity=float(2 ** 20), tp=8)
+    kv_budget=float(2 ** 20), tp=8)
 
 # B200 projection: capacity ratio 2.6235x (Table 1 column 5), W = 2.95 ms,
 # H0 reverse-derived 0.067 ms.  FAIR quality, +-20%.
@@ -130,7 +153,7 @@ B200_LLAMA70B = ManualProfile(
     name="Llama-3.1-70B@B200-SXM(TP8,projected)",
     chip=B200, power_model=B200_POWER,
     roofline=DecodeRoofline(w_ms=2.95, h0_ms=0.067, l_calib=8192),
-    kv_token_capacity=float(2 ** 20) * 2.6235, tp=8)
+    kv_budget=float(2 ** 20) * 2.6235, tp=8)
 
 # H200: same power envelope as H100, 1.41x bandwidth -> W = 4.76 ms,
 # capacity scaled by usable-memory ratio (141-17.5)/(80*0.965-17.5) ~ 2.0.
@@ -138,13 +161,13 @@ H200_LLAMA70B = ManualProfile(
     name="Llama-3.1-70B@H200-SXM(TP8,projected)",
     chip=H200, power_model=H200_POWER,
     roofline=DecodeRoofline(w_ms=4.76, h0_ms=0.0985, l_calib=8192),
-    kv_token_capacity=float(2 ** 20) * 2.0, tp=8)
+    kv_budget=float(2 ** 20) * 2.0, tp=8)
 
 GB200_LLAMA70B = ManualProfile(
     name="Llama-3.1-70B@GB200-NVL(TP8,projected)",
     chip=GB200, power_model=GB200_POWER,
     roofline=DecodeRoofline(w_ms=2.95, h0_ms=0.067, l_calib=8192),
-    kv_token_capacity=float(2 ** 20) * 2.95, tp=8)
+    kv_budget=float(2 ** 20) * 2.95, tp=8)
 
 # Fleet-analysis B200 profile per the paper's stated §4.1 methodology:
 # "B200 uses a profile scaled proportionally from H100 by the 2.62x KV-budget
@@ -157,7 +180,7 @@ B200_LLAMA70B_FLEET = ManualProfile(
     name="Llama-3.1-70B@B200-SXM(TP8,fleet-scaled)",
     chip=B200, power_model=B200_POWER,
     roofline=DecodeRoofline(w_ms=2.95, h0_ms=0.139, l_calib=8192),
-    kv_token_capacity=float(2 ** 20) * 2.6235, tp=8)
+    kv_budget=float(2 ** 20) * 2.6235, tp=8)
 
 # Beyond-paper: the same 70B served on a TPU-v5e slice (16 chips, model axis).
 V5E_LLAMA70B = computed_profile(LLAMA31_70B, TPU_V5E, TPU_V5E_POWER, tp=16,

@@ -1,7 +1,8 @@
-"""Decode roofline model (paper §2.2): tau(n, L) = W + H(L) * n.
+"""Decode roofline model (paper §2.2): tau(n, L) = W + (S + H(L)) * n.
 
 W  — weight-streaming time per decode iteration (all touched weight bytes
      divided by HBM bandwidth; for MoE, only *active* expert bytes).
+S  — per-sequence recurrent-state read and write (0 without state layers).
 H(L) — per-sequence KV-scan overhead, linear in the mean KV length L:
      H(L) = H0 * L / L_calib.
 
@@ -24,13 +25,15 @@ class DecodeRoofline:
     w_ms: float            # weight-streaming ms / iteration
     h0_ms: float           # KV-scan ms / sequence at L = l_calib
     l_calib: float = 8192  # calibration context length (tokens)
+    s_ms: float = 0.0      # recurrent-state ms / sequence (any context)
 
     def h_ms(self, mean_context: ArrayLike) -> ArrayLike:
         return self.h0_ms * (np.asarray(mean_context, dtype=float) / self.l_calib)
 
     def tau_ms(self, n: ArrayLike, mean_context: ArrayLike) -> ArrayLike:
         """Per-iteration decode latency at n in-flight sequences (ms)."""
-        return self.w_ms + self.h_ms(mean_context) * np.asarray(n, dtype=float)
+        return self.w_ms + (self.s_ms + self.h_ms(mean_context)) \
+            * np.asarray(n, dtype=float)
 
     def tokens_per_s(self, n: ArrayLike, mean_context: ArrayLike) -> ArrayLike:
         n = np.asarray(n, dtype=float)
@@ -45,6 +48,7 @@ class DecodeRoofline:
     def from_first_principles(*, weight_bytes_per_gpu: float,
                               kv_bytes_per_token_per_gpu: float,
                               mem_bw_Bps: float,
+                              state_bytes_per_seq_per_gpu: float = 0.0,
                               l_calib: float = 8192,
                               weight_stream_efficiency: float = 0.777,
                               kv_scan_efficiency: float = 0.968) -> "DecodeRoofline":
@@ -53,9 +57,14 @@ class DecodeRoofline:
         Efficiency factors are calibrated so the H100 Llama-3.1-70B profile
         reproduces the paper's measured W = 6.72 ms and Table-1 tok/W:
         17.5 GB / (0.777 * 3.35 TB/s) = 6.72 ms; 55 KB * 8192 / (0.968 * 3.35
-        TB/s) = 0.139 ms.
+        TB/s) = 0.139 ms.  A step reads and writes each sequence's
+        recurrent state once, at the KV scan's efficiency: S = 2 sigma /
+        (kv_scan_efficiency * bw).
         """
         w_ms = weight_bytes_per_gpu / (weight_stream_efficiency * mem_bw_Bps) * 1e3
         h0_ms = (kv_bytes_per_token_per_gpu * l_calib
                  / (kv_scan_efficiency * mem_bw_Bps) * 1e3)
-        return DecodeRoofline(w_ms=w_ms, h0_ms=h0_ms, l_calib=l_calib)
+        s_ms = (2.0 * state_bytes_per_seq_per_gpu
+                / (kv_scan_efficiency * mem_bw_Bps) * 1e3)
+        return DecodeRoofline(w_ms=w_ms, h0_ms=h0_ms, l_calib=l_calib,
+                              s_ms=s_ms)

@@ -46,13 +46,13 @@ def speculative_tok_per_watt(target: BaseProfile, draft: BaseProfile,
     a = accept_rate
     exp_tokens = (1 - a ** (L + 1)) / (1 - a) if a < 1 else L + 1
     # target verify round: weight stream once + KV scan once per position
-    tau_t = (target.roofline.w_ms
-             + target.roofline.h_ms(window) * n) * 1e-3
-    # draft co-located on the target's TP group: its per-step W and H
+    tau_t = target.roofline.tau_ms(n, window) * 1e-3
+    # draft co-located on the target's TP group: its per-step W, S and H
     # shrink by the TP factor relative to a standalone single-chip draft
     tp_scale = target.tp / max(draft.tp, 1)
-    tau_d = L * (draft.roofline.w_ms / tp_scale
-                 + draft.roofline.h_ms(window) / tp_scale * n) * 1e-3
+    rd = draft.roofline
+    tau_d = L * (rd.w_ms / tp_scale
+                 + (rd.s_ms + rd.h_ms(window)) / tp_scale * n) * 1e-3
     round_s = tau_t + tau_d
     tok_s = n * exp_tokens / round_s
     power = target.power_w(n) * (1.0 + draft_power_overhead)

@@ -303,9 +303,15 @@ class TopologySpec:
         and serving behaviour — the perf-baseline key for searched fleets
         (benchmarks/perf_diff.py), and the search memo key."""
         def _prof(pr: BaseProfile) -> tuple:
-            return (pr.name, pr.chip.name, pr.tp,
-                    round(pr.kv_token_capacity, 3),
-                    round(pr.roofline.w_ms, 6))
+            canon = (pr.name, pr.chip.name, pr.tp,
+                     round(pr.kv_token_capacity, 3),
+                     round(pr.roofline.w_ms, 6))
+            # recurrent state appended only when held, so every stateless
+            # profile keys as it did before state existed
+            if pr.state_bytes_per_seq or pr.roofline.s_ms:
+                canon += (round(pr.state_bytes_per_seq, 3),
+                          round(pr.roofline.s_ms, 9))
+            return canon
         canon = (
             self.kind, self.metric, self.accounting,
             round(self.misroute_rate, 9), self.detect_tokens,

@@ -157,18 +157,30 @@ class ArchConfig:
         attn_frac = (self.attn_block_count / self.n_layers
                      if self.n_layers else 0.0)
         n_kv = self.n_kv_heads if self.attn_block_count > 0 else 0
+        # one state layer's recurrent state per sequence: Mamba-2's fp32
+        # SSM state plus its conv state of (d_inner + 2 ssm_state) channels
+        # (one group) x (d_conv - 1) columns in the weights' dtype; RWKV6's
+        # fp32 wkv state
         state_bytes = 0.0
-        if any(b.kind == "mamba2" for b in self.unit):
-            state_bytes = (self.ssm_heads * self.ssm_head_dim * self.ssm_state
-                           * 4.0)
-        if any(b.kind == "rwkv6" for b in self.unit):
-            state_bytes = (self.rwkv_heads * self.rwkv_head_dim ** 2 * 4.0)
+        n_state = 0
+        for kind in ("mamba2", "rwkv6"):
+            per_unit = sum(1 for b in self.unit if b.kind == kind)
+            if not per_unit:
+                continue
+            n_state += per_unit * self.n_repeat
+            if kind == "mamba2":
+                state_bytes = (self.ssm_heads * self.ssm_head_dim
+                               * self.ssm_state * 4.0
+                               + (self.d_inner + 2 * self.ssm_state)
+                               * (self.d_conv - 1) * dtype_bytes)
+            else:
+                state_bytes = self.rwkv_heads * self.rwkv_head_dim ** 2 * 4.0
         return ModelSpec(
             name=self.name, n_params=self.param_count(),
             n_layers=max(self.attn_block_count, 1),
             n_kv_heads=n_kv, head_dim=self.hd, dtype_bytes=dtype_bytes,
             n_active_params=self.moe_active_params(),
-            state_bytes_per_layer=state_bytes,
+            state_bytes_per_layer=state_bytes, n_state_layers=n_state,
             attn_layer_fraction=1.0)  # n_layers above == attn layers already
 
     def reduced(self, *, n_repeat: int = 2, d_model: int = 256,

@@ -174,6 +174,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
 
     st0 = dict(
         sim_time=zero_f(I), qpos=zero_i(I), it=jnp.asarray(0, i32),
+        slot_iters=jnp.asarray(0, i32),
         active=jnp.zeros((I, S), bool),
         pos=zero_i(I, S), gen_count=zero_i(I, S), m_gen=zero_i(I, S),
         max_new=zero_i(I, S), prefill_left=zero_i(I, S),
@@ -302,7 +303,8 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
         has_dec = n_dec > 0
         nf = n_dec.astype(f64)
         mean_ctx = (st["pos"] * dec).sum(1) / jnp.where(has_dec, n_dec, 1)
-        tau_ms = p["w_ms"] + (p["h0_ms"] * (mean_ctx / p["l_calib"])) * nf
+        tau_ms = p["w_ms"] + (p["s_ms"] + p["h0_ms"]
+                              * (mean_ctx / p["l_calib"])) * nf
         tau_s = tau_ms * 1e-3
         safe_b = jnp.maximum(nf, 1e-9)
         logistic = p["p_range"] / (
@@ -391,7 +393,8 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
         nf = n.astype(f64)
         no_pf = ~(act & (st["prefill_left"] > 0)).any(1)
         c0 = (st["pos"] * act).sum(1) / jnp.where(has_act, n, 1)
-        tau1 = (p["w_ms"] + (p["h0_ms"] * (c0 / p["l_calib"])) * nf) * 1e-3
+        tau1 = (p["w_ms"] + (p["s_ms"] + p["h0_ms"] * (c0 / p["l_calib"]))
+                * nf) * 1e-3
         dtau = (p["h0_ms"] / p["l_calib"]) * nf * 1e-3
         big = jnp.asarray(1 << 30, i32)
         bigf = jnp.asarray(float(1 << 30), f64)
@@ -518,6 +521,8 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
             st, sim, n_occ = decode_step(st, sim)
         st["slot_seconds"] += n_occ * (sim - t_start)
         st["m_slot_seconds"] += n_occ * window_overlap(t_start, sim)
+        # occupied slots this iteration, summed over the loop
+        st["slot_iters"] = st["slot_iters"] + n_occ.sum(dtype=i32)
         if phase != "prefill":
             st, sim = coast(st, sim)
         st["sim_time"] = sim
@@ -591,9 +596,10 @@ def drain_engines(engines: Sequence["JaxPoolEngine"], *,
             rows = [packed[id(e)] for e in engs]
             i_tot = sum(e.instances for e in engs)
             i_pad = _bucket(max(i_tot, i_floor))
+            slots = sum(e.instances * e.n_slots for e in engs)
             with host_span("drain.group", phase=phase, rows=i_tot,
-                           rows_padded=i_pad, s_pad=s_pad, q_pad=q_pad,
-                           lookup=lookup):
+                           rows_padded=i_pad, slots=slots, s_pad=s_pad,
+                           q_pad=q_pad, lookup=lookup):
                 with host_span("drain.stack"):
                     merged = _stack(rows, i_pad, q_pad)
                 with host_span("drain.launch"):
@@ -603,8 +609,10 @@ def drain_engines(engines: Sequence["JaxPoolEngine"], *,
                     out = jax.block_until_ready(out)
                 with host_span("drain.fetch"):
                     out = {k: np.asarray(v) for k, v in out.items()}
-                # iteration-weighted real and padded queue entries: the
-                # loop reads every (i_pad, q_pad) entry each step
+                # iteration-weighted real and padded queue entries and
+                # occupied and padded slots: the loop reads every (i_pad,
+                # q_pad) entry and selects over every (i_pad, s_pad) slot
+                # each step
                 it = int(out["it"])
                 host_count("drain.groups", 1)
                 if lookup == "select":
@@ -613,6 +621,8 @@ def drain_engines(engines: Sequence["JaxPoolEngine"], *,
                 host_count("drain.entry_iters",
                            it * sum(int(r["qlen"].sum()) for r in rows))
                 host_count("drain.entry_iters_padded", it * i_pad * q_pad)
+                host_count("drain.slot_iters", int(out["slot_iters"]))
+                host_count("drain.slot_iters_padded", it * i_pad * s_pad)
                 with host_span("drain.split"):
                     _split(out, engs, rows)
 
@@ -654,7 +664,7 @@ def _split(out: Dict[str, np.ndarray], engs: Sequence["JaxPoolEngine"],
         Q = packed["q_ready"].shape[1]
         res = {}
         for k, v in out.items():
-            if v.ndim == 0:             # the shared `it` counter
+            if v.ndim == 0:     # the group's `it` and `slot_iters`
                 res[k] = v
                 continue
             s = v[off:off + I]
@@ -721,6 +731,7 @@ class JaxPoolEngine(BatchedPoolEngine):
             q_ready=q_ready, q_plen=q_plen, q_maxnew=q_maxnew, q_esc=q_esc,
             q_pdone=q_pdone, qlen=self.qlen.astype(np.int32),
             w_ms=ff(rl.w_ms), h0_ms=ff(rl.h0_ms), l_calib=ff(rl.l_calib),
+            s_ms=ff(rl.s_ms),
             p_idle=ff(pm.p_idle_w), p_range=ff(pm.p_range_w),
             k=ff(pm.k), x0=ff(pm.x0), p_nom=ff(pm.p_nom_w),
             pf_num=ff(2.0 * self._streamed_params),

@@ -163,6 +163,28 @@ def test_drain_counters_equal_what_the_drain_returned(traced):
         == sorted(shapes)
 
 
+def test_slot_counters_measure_the_padded_slot_axis(traced):
+    """`drain.slot_iters` is the loop's occupied slots summed over its
+    iterations, as the drain returned it once per group, within the padded
+    `drain.slot_iters_padded` (it x the padded (I, S) grid); each group's
+    span names its real slots."""
+    rec, _, finals = traced
+    groups = {}
+    for eng, res in finals:
+        groups.setdefault(id(res["it"]), (res, []))[1].append(eng)
+    live = padded = 0
+    slots = []
+    for res, engs in groups.values():
+        rows = sum(e.instances for e in engs)
+        live += int(res["slot_iters"])
+        padded += int(res["it"]) * _bucket(rows) * _bucket(engs[0].n_slots)
+        slots.append(sum(e.instances * e.n_slots for e in engs))
+    assert rec.counters["drain.slot_iters"] == live > 0
+    assert rec.counters["drain.slot_iters_padded"] == padded >= live
+    assert sorted(s[4]["slots"] for s in rec.spans
+                  if s[0] == "drain.group") == sorted(slots)
+
+
 def test_grid_reports_are_bit_identical_with_the_recorder_off(traced):
     _, reports_on, _ = traced
     assert _grid() == reports_on

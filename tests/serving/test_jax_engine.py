@@ -21,7 +21,10 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
-from repro.core.modelspec import LLAMA31_70B
+from repro.core.hardware import H100
+from repro.core.modelspec import LLAMA31_70B, ModelSpec
+from repro.core.moe import moe_profile
+from repro.core.power import H100_POWER
 from repro.core.profiles import B200_LLAMA70B, H100_LLAMA70B
 from repro.core.workloads import AZURE
 from repro.models.compat import enable_x64
@@ -30,6 +33,12 @@ from repro.serving.engine import _NEVER
 from repro.serving.jax_engine import JaxPoolEngine, drain_engines
 
 STREAMED = LLAMA31_70B.streamed_params
+# a hybrid Mamba-2 binding (Nemotron-3-Super's shape): each sequence's
+# recurrent state adds S ms to every decode step it is in
+HYBRID = moe_profile(ModelSpec(
+    "hybrid", n_params=120e9, n_layers=88, n_kv_heads=2, head_dim=128,
+    n_active_params=12e9, attn_layer_fraction=8 / 88, n_state_layers=40,
+    state_bytes_per_layer=4_255_744.0), H100, H100_POWER, tp=8)
 
 
 def _req(rid, plen, out, t=0.0, pred=None, esc=None, pdone=False):
@@ -163,11 +172,23 @@ def _case_ragged():
                   phase="prefill"))]
 
 
+def _case_hybrid():
+    """A stateful profile over 128 slots: long decodes fill the slot axis
+    and coast between completions while the rest of the queue waits."""
+    rng = np.random.default_rng(23)
+    reqs = [[_req(i + 1000 * j, int(rng.integers(16, 600)),
+                  int(rng.integers(200, 1500)), t=0.002 * i)
+             for i in range(160)] for j in range(2)]
+    return [(reqs, dict(window=8192, n_slots=128, prefill_chunk=512,
+                        profile=HYBRID))]
+
+
 CASES = {"interleave": _case_interleave,
          "overflow_chain": _case_overflow_chain,
          "escalation_in_window": _case_escalation_in_window,
          "prefill_fifo": _case_prefill_fifo,
-         "ragged": _case_ragged}
+         "ragged": _case_ragged,
+         "hybrid": _case_hybrid}
 
 
 def test_jax_parity_admission_and_chunked_interleave():
@@ -233,6 +254,19 @@ def _drain_case(specs):
     for e in jxs:
         e.run_until_drained(max_iters=200_000)   # consumes staged result
     return refs, jxs, staged
+
+
+def test_jax_parity_hybrid_state_step_coasts():
+    """S enters the compiled step and the coast's closed form as it enters
+    the numpy oracle's roofline: a stateful drain that coasts matches."""
+    (reqs, kw), = _case_hybrid()
+    refs, jxs, staged = _drain_case([(reqs, kw)])
+    assert HYBRID.roofline.s_ms > 0 and jxs[0].n_slots >= 128
+    _assert_parity(refs[0], jxs[0])
+    # fewer loop iterations than the longest request has decode steps
+    longest = max(r.max_new_tokens for q in reqs for r in q)
+    assert 0 < int(staged[0]["it"]) < longest
+    assert int(jxs[0].bank.tokens.sum()) > 100 * longest
 
 
 def test_drain_engines_ragged_batch():
