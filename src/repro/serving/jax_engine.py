@@ -29,8 +29,10 @@ Layout / padding / masking
 Parity contract: every meter expression replicates `energy.MeterBank`
 operation-for-operation in float64 (`repro.models.compat.enable_x64` is
 scoped to the drain so the model-mode f32 default is untouched).  The only
-divergence is accumulation *order* on multi-slot chunk spills (the numpy
-slow path charges sequentially; the kernel sums a masked cumsum), which is
+divergence is accumulation *order* on multi-slot chunk spills: the numpy
+slow path advances the clock slot by slot, the kernel takes each slot's
+charge instant in closed form from the spill's int32 token prefix (one
+rounding of pf_num·Σtake/pf_den, no scan over the float clock), which is
 last-ulp noise — the acceptance gate is 0.1% per tok/W cell, the observed
 delta is ~1e-12 relative.  The decode-token LCG stream is elided entirely:
 token *values* never feed back into any meter or event (the analytical
@@ -228,18 +230,29 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
         return jnp.maximum(0.0, jnp.minimum(t1, end)
                            - jnp.maximum(t0, start))
 
-    def charge_prefill_span(st, take, overlap_s, sim):
+    def charge_prefill_span(st, take, cum_excl, overlap_s, sim):
         """Vectorized twin of the numpy engine's sequential per-slot chunk
         charges: per-slot work times via `MeterBank.charge_prefill_rows`'s
-        expressions, per-slot charge instants via an exclusive cumsum of
-        the clock advances (the numpy slow path's sequential `sim_time`).
-        Returns (st, sim', t_after) with t_after the post-charge instant
-        per slot (first-token / handoff timestamps)."""
-        t = (p["pf_num"][:, None] * take) / p["pf_den"][:, None]
+        expressions, per-slot charge instants (the numpy slow path's
+        sequential `sim_time`) in closed form from the spill's int32
+        exclusive token prefix `cum_excl`.  The spill is a water-fill in
+        slot order: a slot s is charged only while cum_excl[s] < chunk,
+        and then every earlier pending slot drained whole, so cum_excl[s]
+        tokens were charged before it, and the clock advanced before it
+        by their work time less the one hidden charge (only the first
+        charged slot hides, and it lies before s exactly when
+        cum_excl[s] > 0).  Uncharged slots' instants are never read.  No
+        scan runs over the float clock: an f64 cumsum is an O(S^2)
+        emulated reduce-window on the TPU.  Returns (st, sim', t_after)
+        with t_after the post-charge instant per slot (first-token /
+        handoff timestamps)."""
+        pf_num, pf_den = p["pf_num"][:, None], p["pf_den"][:, None]
+        t = (pf_num * take) / pf_den
         e = p["p_nom"][:, None] * t
         hidden = jnp.minimum(overlap_s, t)
         dt = t - hidden
-        cum_dt_excl = jnp.cumsum(dt, axis=1) - dt
+        cum_dt_excl = (pf_num * cum_excl) / pf_den - jnp.where(
+            cum_excl > 0, hidden.sum(1)[:, None], 0.0)
         t_before = sim[:, None] + cum_dt_excl
         ovl = window_overlap(t_before - hidden, t_before + dt)
         safe_t = jnp.where(t > 0, t, 1.0)
@@ -362,9 +375,11 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
         take = jnp.minimum(pl, jnp.maximum(p["chunk"][:, None]
                                            - cum_excl, 0))
         charged = take > 0
-        is_first = charged & ((jnp.cumsum(charged, axis=1) - charged) == 0)
+        # a positive chunk always charges the first pending slot, whose
+        # exclusive prefix is the only zero one among charged slots
+        is_first = charged & (cum_excl == 0)
         ov = jnp.where(is_first, tau_full[:, None], 0.0)
-        st, sim, t_after = charge_prefill_span(st, take, ov, sim)
+        st, sim, t_after = charge_prefill_span(st, take, cum_excl, ov, sim)
         drained = charged & (take == pl)
         st = emit(st, drained, None, None, first=t_after)
         st["gen_count"] = jnp.where(drained, 1, st["gen_count"])
@@ -475,7 +490,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
         take_srt = jnp.minimum(pl_srt,
                                jnp.maximum(p["chunk"][:, None] - cum_excl, 0))
         st, sim, t_after_srt = charge_prefill_span(
-            st, take_srt, jnp.zeros((I, S)), sim)
+            st, take_srt, cum_excl, jnp.zeros((I, S)), sim)
         drained_srt = (take_srt > 0) & (take_srt == pl_srt)
         take = take_rows(take_srt, inv)
         drained = take_rows(drained_srt, inv)

@@ -13,6 +13,7 @@ bit-exact parity contract against the scalar engines untouched
 (tests/serving/test_soa_parity.py).
 """
 import copy
+import re
 from functools import partial
 
 import numpy as np
@@ -183,12 +184,32 @@ def _case_hybrid():
                         profile=HYBRID))]
 
 
+def _case_wide_spill():
+    """A wide pool fed bursts of short prompts, in each phase: every
+    512-token chunk spills over three to five slots in one step and ends
+    mid-slot, and in the decode phase its first charge hides behind the
+    decode tau of the slots already generating; the measurement window
+    closes mid-run."""
+    rng = np.random.default_rng(29)
+
+    def streams(base, out_hi):
+        return [[_req(base + i + 1000 * j, int(rng.integers(90, 170)),
+                      int(rng.integers(1, out_hi + 1)), t=0.05 * (i // 32))
+                 for i in range(96)] for j in range(2)]
+
+    return [(streams(0, 200), dict(window=8192, n_slots=64,
+                                   prefill_chunk=512, measure=(0.02, 0.3))),
+            (streams(5000, 1), dict(window=8192, n_slots=64,
+                                    prefill_chunk=512, phase="prefill"))]
+
+
 CASES = {"interleave": _case_interleave,
          "overflow_chain": _case_overflow_chain,
          "escalation_in_window": _case_escalation_in_window,
          "prefill_fifo": _case_prefill_fifo,
          "ragged": _case_ragged,
-         "hybrid": _case_hybrid}
+         "hybrid": _case_hybrid,
+         "wide_spill": _case_wide_spill}
 
 
 def test_jax_parity_admission_and_chunked_interleave():
@@ -297,6 +318,47 @@ def test_select_lowering_drains_bit_identically(case, monkeypatch):
         for k in a:
             assert a[k].dtype == b[k].dtype and a[k].shape == b[k].shape, k
             assert a[k].tobytes() == b[k].tobytes(), k
+
+
+def test_wide_spill_case_charges_three_slots_a_step():
+    """The wide-spill case does what it is there for: some prefill steps
+    hand off three or more prompts at once, so the chunk's clock prefix
+    runs over several charged slots."""
+    refs, jxs, staged = _drain_case(_case_wide_spill())
+    for ref, jx in zip(refs, jxs):
+        _assert_parity(ref, jx)
+    out = staged[1]
+    handoff = out["out_kind"] == jax_engine._EV_HANDOFF
+    for i in range(out["out_kind"].shape[0]):
+        per_step = np.bincount(out["out_step"][i][handoff[i]])
+        assert per_step.max() >= 3, per_step
+
+
+# --- the lowered drain's prefix scans ------------------------------------
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_drain_scans_only_int32(phase, platform):
+    """At the hybrid binding's padded shape (I=8, S=1024, Q=2048) every
+    prefix scan of the lowered drain is over int32: a float64 or int64
+    `cumsum` lowers to a reduce_window that the TPU emulates in O(S^2)
+    adds of 32-bit pairs, every loop iteration."""
+    I, S, Q = 8, 1024, 2048
+    eng = JaxPoolEngine(instances=I, window=8192, n_slots=S, profile=HYBRID,
+                        streamed_params=STREAMED, prefill_chunk=512,
+                        respect_arrival=True, phase=phase)
+    with enable_x64():
+        args = {k: jax.ShapeDtypeStruct(
+                    (I, Q) if np.ndim(a) == 2 else np.shape(a),
+                    np.asarray(a).dtype)
+                for k, a in eng._pack(max_iters=1000).items()}
+        text = jax_engine._drain.lower(args, phase=phase, n_slots_pad=S,
+                                       platform=platform).as_text()
+    # the operand type of every reduce_window, which `cumsum` lowers to
+    ops = re.findall(r'"stablehlo\.reduce_window"\(.*?\}\) : '
+                     r'\((tensor<[^>]*>)', text, re.S)
+    assert ops                       # admit's free-slot ranks at least
+    assert all(o.endswith("xi32>") for o in ops), ops
 
 
 # --- the two lowerings of a row lookup, value by value -------------------

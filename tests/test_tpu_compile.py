@@ -4,8 +4,8 @@ Nothing here runs on a chip: the TPU compiler installed with JAX compiles
 for a v5e that is described, not attached, and refuses what the chip
 would refuse (tile alignment, VMEM use, unsupported ops).  Covered: the
 four Pallas kernels at real model widths, which must lower to a Mosaic
-`tpu_custom_call`, and one small signature of the fleet drain with its
-float64 meters.
+`tpu_custom_call`, and two signatures of the fleet drain with its
+float64 meters, one at the hybrid binding's 1024-slot width.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library at a time, and every test worker imports
@@ -13,6 +13,8 @@ this file.  The persistent compilation cache is off around these
 compiles; a cache entry compiled for a described chip cannot be read
 back without one.
 """
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -86,11 +88,18 @@ def test_kernel_compiles_for_v5e(name, one_chip):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def test_fleet_drain_compiles_for_v5e(one_chip):
-    """One small drain signature (I=32 instances, S=16 slots, Q=8 queue
-    entries), float64 meters included; on the TPU its row lookups are
-    selects, and no gather is left."""
-    I, S, Q = 32, 16, 8
+# (I instances, S slots, Q queue entries): a small signature, and the
+# hybrid binding's 8K pool padded as its cell runs it
+DRAIN_SHAPES = {"small": (32, 16, 8), "hybrid_8k": (8, 1024, 2048)}
+
+
+@pytest.mark.parametrize("shape", sorted(DRAIN_SHAPES))
+def test_fleet_drain_compiles_for_v5e(shape, one_chip):
+    """A decode drain signature, float64 meters included; on the TPU its
+    row lookups are selects, so no gather is left, and every prefix scan
+    (a reduce-window) is over s32: an f64 or s64 one is emulated in
+    O(S^2) adds and takes minutes to compile at S=1024."""
+    I, S, Q = DRAIN_SHAPES[shape]
     eng = jax_engine.JaxPoolEngine(
         instances=I, window=4096, n_slots=S, profile=H100_LLAMA70B,
         streamed_params=LLAMA31_70B.streamed_params, prefill_chunk=512,
@@ -105,3 +114,7 @@ def test_fleet_drain_compiles_for_v5e(one_chip):
     text = compiled.as_text()
     assert "f64" in text
     assert " gather(" not in text
+    scans = re.findall(r"= (.*?) reduce-window\(", text)
+    assert scans
+    dtypes = {d for t in scans for d in re.findall(r"(\w+)\[", t)}
+    assert dtypes == {"s32"}, scans
