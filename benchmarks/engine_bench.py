@@ -37,7 +37,6 @@ def run():
         "long": PoolEngine(cfg, params, window=128, profile=H100_LLAMA70B,
                            n_slots=2, name="long")}
     router = ContextRouter(pools, RouterPolicy(
-        kind="fleetopt", b_short=16, gamma=2.0,
         ladder=[("short", 32.0), ("long", math.inf)]))
     reqs = [Request(rid=i, prompt=rng.integers(0, cfg.vocab,
                                                6 if i % 4 else 90),

@@ -53,9 +53,10 @@ from repro.core.multipool import ladder_windows
 from repro.core.power import B200_POWER, GB200_POWER, H100_POWER, H200_POWER
 from repro.core.profiles import (B200_LLAMA70B_FLEET, GB200_LLAMA70B,
                                  H100_LLAMA70B, H200_LLAMA70B)
+from repro.core.topospec import TopologySpec
 from repro.core.workloads import AZURE
 from repro.models.compat import enable_compile_cache
-from repro.serving import prepare_topology, run_fleet_grid
+from repro.serving import prepare_spec, run_fleet_grid
 
 from .fleet_sim_bench import BENCH_JSON, _TableTimer, write_bench_json
 
@@ -153,10 +154,11 @@ def run(n_requests: int = DEFAULT_N_REQUESTS, seed: int = 0,
     for family, fam_cells in by_family.items():
         for i in range(0, len(fam_cells), max(width, 1)):
             chunk = fam_cells[i:i + max(width, 1)]
-            scenarios = [prepare_topology(kind, AZURE, prof, mdl,
-                                          n_requests=n_requests, seed=seed,
-                                          engine=engine, **kw)
-                         for _, kind, prof, mdl, kw in chunk]
+            scenarios = [prepare_spec(
+                TopologySpec.from_kind(kind, prof, mdl, misroute_seed=seed,
+                                       **kw),
+                AZURE, n_requests=n_requests, seed=seed, engine=engine)
+                for _, kind, prof, mdl, kw in chunk]
             floors = SHAPE_CLASSES if engine == "jax" else None
             for (label, *_), cell in zip(
                     chunk, run_fleet_grid(scenarios, pad_floors=floors)):

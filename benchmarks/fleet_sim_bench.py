@@ -12,7 +12,7 @@ TokenPowerBench-style.
 
 Table B (SLO-constrained) is the bugfix headline: PR 1 showed the fleets
 Table A is quoted for *violate* the paper's P99 TTFT <= 500 ms SLO when
-actually run.  `core.slo.size_to_slo` re-provisions each topology until
+actually run.  `core.slo.size_to_slo_spec` re-provisions each topology until
 the measured TTFT p99 complies; every Table B cell reports the
 SLO-feasible tok/W (the new headline metric next to Eq. 4's unconstrained
 number) and its measured TTFT p99 — all <= 0.5 s by construction.  The
@@ -25,7 +25,7 @@ through FleetSim: homo vs fleetopt vs disagg vs disagg+fleetopt on
 Azure/H100, analytical (whole-fleet and decode-only) vs measured vs
 SLO-constrained, with the KV-handoff energy the interconnect really
 charges.  Gates: every disagg cell's measured TTFT p99 <= 500 ms after
-size_to_slo; if disagg+fleetopt's measured all-in tok/W falls short of
+size_to_slo_spec; if disagg+fleetopt's measured all-in tok/W falls short of
 plain fleetopt's, the bench prints the shortfall and the KV-handoff cost
 that (partially) explains it instead of failing.
 
@@ -38,7 +38,7 @@ error with its escalation traffic) vs Qwen3-235B-A22B as a `moe_pool` at
 dispatch_ms in {0, 2, 10} and as the large model of `moe_semantic` —
 analytical vs measured vs SLO-constrained (with the post-compliance trim
 phase).  Azure in --quick; Azure + Agent in the full run.  Gate: every
-Table D cell is SLO-compliant after size_to_slo.
+Table D cell is SLO-compliant after size_to_slo_spec.
 
 `--json PATH` dumps {"meta", "rows"} for CI's perf-regression diff
 (benchmarks/perf_diff.py --fleet against the committed
@@ -63,7 +63,7 @@ import platform
 import sys
 import time
 
-from repro.core import ladder_windows, size_to_slo
+from repro.core import TopologySpec, ladder_windows, size_to_slo_spec
 from repro.core.hardware import H100
 from repro.core.modelspec import LLAMA31_70B, QWEN3_235B_A22B
 from repro.core.moe import moe_profile
@@ -71,7 +71,7 @@ from repro.core.power import H100_POWER
 from repro.core.profiles import (B200_LLAMA70B_FLEET, H100_LLAMA70B,
                                  H200_LLAMA70B)
 from repro.core.workloads import AGENT, AZURE, LMSYS
-from repro.serving import FleetSim, simulate_topology
+from repro.serving import FleetSim, simulate_spec
 
 BENCH_JSON = pathlib.Path(__file__).resolve().parent / "results" \
     / "BENCH_fleet_sim.json"
@@ -126,12 +126,12 @@ def table_d(workloads, *, n_requests: int, slo_requests: int, seed: int,
     rows = []
     for wl in workloads:
         for kind, prof, mdl, kw in _table_d_cells(wl):
-            cell = simulate_topology(kind, wl, prof, mdl,
-                                     n_requests=n_requests, seed=seed,
-                                     engine=engine, **kw)
-            res = size_to_slo(kind, wl, prof, mdl,
-                              n_requests=slo_requests, seed=seed,
-                              engine=engine, **kw)
+            spec = TopologySpec.from_kind(kind, prof, mdl,
+                                          misroute_seed=seed, **kw)
+            cell = simulate_spec(spec, wl, n_requests=n_requests, seed=seed,
+                                 engine=engine)
+            res = size_to_slo_spec(spec, wl, n_requests=slo_requests,
+                                   seed=seed, engine=engine)
             f = cell.report["fleet"]
             rows.append(dict(
                 table="model_hetero", workload=wl.name, topology=kind,
@@ -163,8 +163,10 @@ def _slo_cell(kind: str, profile, *, n_requests: int, seed: int,
               engine: str = "numpy"):
     kw = _SLO_CELL_KW.get(
         kind, lambda: dict(b_short=B_SHORT[AZURE.name]))()
-    return size_to_slo(kind, AZURE, profile, LLAMA31_70B,
-                       n_requests=n_requests, seed=seed, engine=engine, **kw)
+    spec = TopologySpec.from_kind(kind, profile, LLAMA31_70B,
+                                  misroute_seed=seed, **kw)
+    return size_to_slo_spec(spec, AZURE, n_requests=n_requests, seed=seed,
+                            engine=engine)
 
 
 class _TableTimer:
@@ -204,10 +206,11 @@ def run(n_requests: int = 10_000, slo_requests: int = 3000, seed: int = 0,
     rows = []
     for wl in (AZURE, LMSYS, AGENT):
         for kind in TOPOLOGIES:
-            cell = simulate_topology(
-                kind, wl, H100_LLAMA70B, LLAMA31_70B,
-                b_short=B_SHORT[wl.name], n_requests=n_requests, seed=seed,
-                engine=engine)
+            spec = TopologySpec.from_kind(
+                kind, H100_LLAMA70B, LLAMA31_70B, b_short=B_SHORT[wl.name],
+                misroute_seed=seed)
+            cell = simulate_spec(spec, wl, n_requests=n_requests, seed=seed,
+                                 engine=engine)
             f = cell.report["fleet"]
             rows.append(dict(cell.row(), table="unconstrained",
                              occupancy={r: s["occupancy"]
@@ -228,13 +231,13 @@ def run(n_requests: int = 10_000, slo_requests: int = 3000, seed: int = 0,
     # Table A measured + Table B SLO numbers; only the disagg kinds add
     # simulation + SLO-loop work)
     for kind in DISAGG_TOPOLOGIES:
-        cell = simulate_topology(
-            kind, AZURE, H100_LLAMA70B, LLAMA31_70B,
-            b_short=B_SHORT[AZURE.name], n_requests=n_requests, seed=seed,
-            engine=engine)
-        res = size_to_slo(kind, AZURE, H100_LLAMA70B, LLAMA31_70B,
-                          b_short=B_SHORT[AZURE.name],
-                          n_requests=slo_requests, seed=seed, engine=engine)
+        spec = TopologySpec.from_kind(
+            kind, H100_LLAMA70B, LLAMA31_70B, b_short=B_SHORT[AZURE.name],
+            misroute_seed=seed)
+        cell = simulate_spec(spec, AZURE, n_requests=n_requests, seed=seed,
+                             engine=engine)
+        res = size_to_slo_spec(spec, AZURE, n_requests=slo_requests,
+                               seed=seed, engine=engine)
         f = cell.report["fleet"]
         rows.append(dict(
             table="disagg", workload=AZURE.name, topology=kind,
@@ -482,13 +485,13 @@ def main(argv=None) -> None:
                if not r["slo_compliant"] or r["slo_ttft_p99_s"] > 0.5]
     if bad_dis:
         fails.append(f"disagg cells violate the TTFT SLO after"
-                     f" size_to_slo: {bad_dis}")
+                     f" size_to_slo_spec: {bad_dis}")
     bad_het = [f"{r['workload']}/{r['topology']}@d{r['dispatch_ms']:g}"
                for r in het_rows
                if not r["slo_compliant"] or r["slo_ttft_p99_s"] > 0.5]
     if bad_het:
         fails.append(f"Table D cells violate the TTFT SLO after"
-                     f" size_to_slo: {bad_het}")
+                     f" size_to_slo_spec: {bad_het}")
     if fails:
         sys.exit("ACCEPTANCE FAIL: " + "; ".join(fails))
 

@@ -25,8 +25,9 @@ load and warm spare charged.
 """
 from repro.core import (AGENT, AZURE, LMSYS, B200_LLAMA70B_FLEET,
                         H100_LLAMA70B, FleetOpt, Homogeneous, Semantic,
-                        TwoPool, computed_profile, gain_decomposition,
-                        ladder_windows, optimize_gamma, size_to_slo)
+                        TopologySpec, TwoPool, computed_profile,
+                        gain_decomposition, ladder_windows, optimize_gamma,
+                        size_to_slo_spec)
 from repro.core.hardware import H100
 from repro.core.modelspec import LLAMA31_8B, LLAMA31_70B
 from repro.core.power import H100_POWER
@@ -34,13 +35,14 @@ from repro.core.power import H100_POWER
 
 def simulated_crosscheck(n_requests: int = 4000) -> None:
     """Measure the Azure topologies by actually running the fleet."""
-    from repro.serving import simulate_topology
+    from repro.serving import simulate_spec
 
     print(f"\n=== measured (fleet simulator, {n_requests} requests) ===")
     sim_tpw = {}
     for kind in ("homo", "two_pool", "fleetopt"):
-        cell = simulate_topology(kind, AZURE, H100_LLAMA70B, LLAMA31_70B,
-                                 b_short=4096, n_requests=n_requests)
+        spec = TopologySpec.from_kind(kind, H100_LLAMA70B, LLAMA31_70B,
+                                      b_short=4096)
+        cell = simulate_spec(spec, AZURE, n_requests=n_requests)
         f = cell.report["fleet"]
         sim_tpw[kind] = cell.sim_decode_tok_per_watt
         print(f"  {kind:9s} analytical {cell.analytical_tok_per_watt:5.2f}"
@@ -57,13 +59,14 @@ def disaggregated_serving(n_requests: int = 4000) -> None:
     """§10.3 Splitwise: prefill/decode disaggregation served end-to-end —
     dedicated prefill pools, the KV-handoff hop over the interconnect,
     decode pools with zero prefill interference."""
-    from repro.serving import simulate_topology
+    from repro.serving import simulate_spec
 
     print(f"\n=== disaggregated prefill/decode (Azure, H100, "
           f"{n_requests} requests) ===")
     for kind in ("disagg", "disagg_fleetopt"):
-        cell = simulate_topology(kind, AZURE, H100_LLAMA70B, LLAMA31_70B,
-                                 b_short=4096, n_requests=n_requests)
+        spec = TopologySpec.from_kind(kind, H100_LLAMA70B, LLAMA31_70B,
+                                      b_short=4096)
+        cell = simulate_spec(spec, AZURE, n_requests=n_requests)
         f = cell.report["fleet"]
         print(f"  {kind:15s} analytical fleet "
               f"{cell.analytical_fleet_tok_per_watt:5.2f}"
@@ -85,14 +88,15 @@ def model_heterogeneous_serving(n_requests: int = 4000) -> None:
     dispatch floor."""
     from repro.core.modelspec import QWEN3_235B_A22B
     from repro.core.moe import moe_profile
-    from repro.serving import simulate_topology
+    from repro.serving import simulate_spec
 
     print(f"\n=== model-heterogeneous serving (Azure, H100, "
           f"{n_requests} requests) ===")
     for kind, kw in (("semantic", {}),
                      ("semantic_fleetopt", dict(misroute_rate=0.1))):
-        cell = simulate_topology(kind, AZURE, H100_LLAMA70B, LLAMA31_70B,
-                                 b_short=4096, n_requests=n_requests, **kw)
+        spec = TopologySpec.from_kind(kind, H100_LLAMA70B, LLAMA31_70B,
+                                      b_short=4096, **kw)
+        cell = simulate_spec(spec, AZURE, n_requests=n_requests)
         f = cell.report["fleet"]
         print(f"  {kind:17s} mr={kw.get('misroute_rate', 0.0):4.2f}"
               f" | analytical {cell.analytical_tok_per_watt:5.2f}"
@@ -102,9 +106,9 @@ def model_heterogeneous_serving(n_requests: int = 4000) -> None:
               f" {f['migrations']} migrations")
     moe_prof = moe_profile(QWEN3_235B_A22B, H100, H100_POWER, tp=8)
     for d in (0.0, 10.0):
-        cell = simulate_topology("moe_pool", AZURE, moe_prof,
-                                 QWEN3_235B_A22B, n_requests=n_requests,
-                                 dispatch_ms=d)
+        spec = TopologySpec.from_kind("moe_pool", moe_prof, QWEN3_235B_A22B,
+                                      dispatch_ms=d)
+        cell = simulate_spec(spec, AZURE, n_requests=n_requests)
         f = cell.report["fleet"]
         print(f"  moe_pool          d={d:4.0f}ms"
               f" | analytical {cell.analytical_tok_per_watt:5.2f}"
@@ -128,8 +132,8 @@ def slo_constrained_sizing(n_requests: int = 2000) -> None:
              ("B200", B200_LLAMA70B_FLEET, "fleetopt",
               dict(b_short=4096)))
     for gen, prof, kind, kw in cells:
-        res = size_to_slo(kind, AZURE, prof, LLAMA31_70B,
-                          n_requests=n_requests, **kw)
+        spec = TopologySpec.from_kind(kind, prof, LLAMA31_70B, **kw)
+        res = size_to_slo_spec(spec, AZURE, n_requests=n_requests)
         cal = ", ".join(f"{r}={v:.2f}"
                         for r, v in res.calibrated_prefill_mfu.items())
         print(f"  {gen} {kind:9s} Eq.4 {res.unconstrained.tok_per_watt:5.2f}"
@@ -147,7 +151,7 @@ def declarative_topology_ir(n_requests: int = 2000) -> None:
     behind two H100 short rungs), measure it end-to-end, then let
     optimize_topology search the spec space on Azure."""
     from repro.core import SLOSpec, optimize_topology
-    from repro.core.topospec import PoolSpec, TopologySpec
+    from repro.core.topospec import PoolSpec
     from repro.serving import simulate_spec
 
     print(f"\n=== declarative topology IR + search (Azure, "
@@ -188,7 +192,7 @@ def diurnal_autoscaling(peak_rate: float = 150.0, day_s: float = 160.0):
     """A compressed diurnal day, static vs autoscaled (DESIGN.md §13)."""
     import dataclasses
 
-    from repro.core import AutoscalePolicy, TopologySpec
+    from repro.core import AutoscalePolicy
     from repro.core.workloads import DiurnalProfile
     from repro.serving import prepare_spec, sample_diurnal_trace
 

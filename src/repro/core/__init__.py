@@ -31,7 +31,7 @@ from .disagg import Disaggregated
 from .fleet import PoolOverride
 from .multipool import MultiPool, ladder_windows, sweep_pool_counts
 from .slo import (SLOSizingResult, SLOSpec, explain as explain_slo,
-                  size_to_slo, size_to_slo_spec)
+                  size_to_slo_spec)
 from .timeline import (EVENT_NAMES, LIFECYCLE_KINDS, PHASES,
                        TIMELINE_SCHEMA_VERSION, TRACE_SCHEMA_VERSION,
                        MetricsTimeline, bin_intervals, chrome_trace_doc)

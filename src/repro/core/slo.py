@@ -45,13 +45,11 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional
 
 import numpy as np
 
 from .fleet import PREFILL_MFU, FleetReport, PoolOverride
-from .modelspec import ModelSpec
-from .profiles import BaseProfile
 from .topospec import TopologySpec, plan_roles
 from .workloads import Workload
 
@@ -280,12 +278,12 @@ class _FleetMeasurer:
                       steady state via `FleetSim.run(reuse=...)` instead
                       of re-simulated.
 
-    `stats` carries the audit counts `size_to_slo` exposes as
+    `stats` carries the audit counts `size_to_slo_spec` exposes as
     `SLOSizingResult.sim_stats`.
 
     The measurer is keyed on a `TopologySpec` (the IR is the single
-    provisioning authority — `spec.build` replaces the old kind-string
-    `build_topology` plumbing), and the frozen trace can be *injected*
+    provisioning authority — `spec.build` yields the policy, plan and
+    registry every round simulates), and the frozen trace can be *injected*
     (`trace=`): the topology search (`core.topo_search`) sizes many
     candidate specs against one shared trace, so candidate scores differ
     only in topology, never in arrival noise.
@@ -314,7 +312,7 @@ class _FleetMeasurer:
     def _requests(self):
         # fresh mutable Request objects over the frozen trace, built by
         # the one shared construction path (serving.fleetsim) so the SLO
-        # loop can never diverge from simulate_topology's conventions
+        # loop can never diverge from simulate_spec's conventions
         return self._fs.trace_requests(self.workload, self.n_requests,
                                        trace=self._trace)
 
@@ -385,11 +383,12 @@ def size_to_slo_spec(spec: TopologySpec, workload: Workload, *,
     instance per round, for guaranteed progress).
 
     Works for every `TopologySpec` FleetSim can serve — hand-built specs
-    and every `TopologySpec.from_kind` compilation alike (the legacy
-    kind-string front end is `size_to_slo`).  Pass `trace=` to share one
-    frozen arrival trace across many candidate specs (the topology
-    search's common-random-numbers discipline); by default the measurer
-    samples its own trace capped at `spec.max_window`.
+    and every `TopologySpec.from_kind` compilation alike (a kind string
+    is sized as `size_to_slo_spec(TopologySpec.from_kind(kind, ...),
+    workload)`).  Pass `trace=` to share one frozen arrival trace across
+    many candidate specs (the topology search's common-random-numbers
+    discipline); by default the measurer samples its own trace capped at
+    `spec.max_window`.
 
     After compliance, a **trim phase** (`trim=True`) bisects each grown
     pool's instance count back down toward its round-0 sizing, keeping
@@ -593,37 +592,3 @@ def size_to_slo_spec(spec: TopologySpec, workload: Workload, *,
         trimmed=trimmed, trim_rounds=trim_rounds,
         sim_stats=dict(measurer.stats), measured_hol=measured_hol,
         explanation=explain(sim, slo) if sim is not None else [])
-
-
-def size_to_slo(kind: str, workload: Workload, profile: BaseProfile,
-                model: ModelSpec, *, b_short: int = 4096,
-                gamma: float = 2.0,
-                windows: Optional[Sequence[int]] = None,
-                slo: SLOSpec = SLOSpec(),
-                n_requests: int = 3000, seed: int = 0,
-                max_rounds: int = 8, prefill_chunk: int = 512,
-                small_model: Optional[ModelSpec] = None,
-                small_profile: Optional[BaseProfile] = None,
-                misroute_rate: float = 0.0,
-                dispatch_ms: float = 0.0,
-                trim: bool = True,
-                long_window: Optional[int] = None,
-                engine: str = "numpy") -> SLOSizingResult:
-    """Legacy kind-string front end for `size_to_slo_spec`: compile the
-    kind to its `TopologySpec` (`TopologySpec.from_kind` is the single
-    kind-dispatch site in the codebase) and size that.  The frozen-trace
-    cap is `spec.max_window`, which subsumes the old multipool
-    `max(windows)` special case; pass `long_window` to stretch the
-    terminal serve window of the non-multipool kinds."""
-    from .routing import LONG_WINDOW
-
-    spec = TopologySpec.from_kind(
-        kind, profile, model, b_short=b_short, gamma=gamma,
-        long_window=int(long_window) if long_window else LONG_WINDOW,
-        windows=windows, small_model=small_model,
-        small_profile=small_profile, misroute_rate=misroute_rate,
-        dispatch_ms=dispatch_ms, misroute_seed=seed)
-    return size_to_slo_spec(
-        spec, workload, slo=slo, n_requests=n_requests, seed=seed,
-        max_rounds=max_rounds, prefill_chunk=prefill_chunk, trim=trim,
-        engine=engine)

@@ -145,7 +145,6 @@ def ladder_spec(windows: Sequence[int], profiles: Sequence[BaseProfile],
     return TopologySpec(
         kind=kind, pools=tuple(pools), models=models,
         accounting="disagg" if disagg else "subset",
-        b_short=ws[0], gamma=gamma,
         label=label or (f"Searched{[w // 1024 for w in ws]}K/g={gamma:g}"
                         + ("/disagg" if disagg else "")))
 

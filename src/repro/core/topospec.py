@@ -21,7 +21,8 @@ routing metadata.  Every layer derives what it needs from the spec:
                   admission ladder, metric kind and misroute flip pair;
   registry()    — the `serving.models.ModelProfileRegistry` binding each
                   role to the model/profile its pool serves;
-  build()       — (policy, plan, registry), the `build_topology` tuple;
+  build()       — (policy, plan, registry), the tuple `serving.FleetSim`
+                  is constructed from;
   roles / max_window / spec_hash — the derived facts the SLO loop, the
                   trace synthesiser and the perf baseline key off.
 
@@ -130,9 +131,6 @@ class TopologySpec:
     detect_tokens: int = ESCALATION_DETECT_TOKENS
     misroute_seed: int = 0
     flip: Optional[Tuple[str, str]] = None
-    # routing metadata carried onto the RouterPolicy (labels / sweeps)
-    b_short: int = 4096
-    gamma: float = 2.0
     label: str = ""
     # opt-in autoscaling policy (core.autoscale) for non-stationary
     # traffic runs.  `provision()` / the SLO loop ALWAYS size for peak
@@ -589,15 +587,14 @@ class TopologySpec:
         p99 = int(np.quantile(workload.outputs, 0.99)) \
             if self.metric == "prompt_plus_p99" else 1024
         return RouterPolicy(
-            kind=self.kind, b_short=self.b_short, gamma=self.gamma,
-            p99_output=p99, ladder=ladder, metric_kind=self.metric,
+            ladder=ladder, p99_output=p99, metric_kind=self.metric,
             flip=self.flip, misroute_rate=self.misroute_rate,
             detect_tokens=self.detect_tokens,
             misroute_seed=self.misroute_seed, spec=self)
 
     def build(self, workload: Workload, *, pool_overrides=None):
-        """(policy, plan, registry) — the `build_topology` contract,
-        derived entirely from the spec."""
+        """(policy, plan, registry) — what `serving.FleetSim` is built
+        from, derived entirely from the spec."""
         from .fleet import apply_overrides
         plan = self.provision(workload)
         registry = self.registry()
@@ -624,10 +621,9 @@ class TopologySpec:
         dispatch exists.  Pinned bit-exact against the committed
         quick-bench baseline; see DESIGN.md §12 for the full table.
 
-        The serving-twin conventions the legacy `build_topology` encoded
-        are preserved: `fleetopt` routes *and* serves at
-        W = int(gamma * b_short) (admission boundary == short serve
-        window — the analytical twin of the router's
+        The serving-twin conventions of each kind: `fleetopt` routes
+        *and* serves at W = int(gamma * b_short) (admission boundary ==
+        short serve window — the analytical twin of the router's
         `predicted <= gamma * b_short` rung, identical for every
         integral gamma * b_short), the disagg kinds likewise, `semantic`
         serves its small pool at int(g * b_short) with admission at
@@ -648,7 +644,6 @@ class TopologySpec:
                 name=f"homo-{long_window // 1024}K", window=long_window,
                 profile=prof, admit=math.inf, dispatch_ms=dispatch_ms),)
             return cls(kind=kind, pools=pools, models=models,
-                       b_short=b_short, gamma=gamma,
                        label=f"Homo {long_window // 1024}K")
         if kind == "two_pool":
             pools = (
@@ -660,8 +655,8 @@ class TopologySpec:
                          admit=math.inf, hol_inflation=HOL_INFLATION),
             )
             return cls(kind=kind, pools=pools, models=models,
-                       metric="prompt_plus_p99", b_short=b_short,
-                       gamma=gamma, label=f"Pool {b_short // 1024}K")
+                       metric="prompt_plus_p99",
+                       label=f"Pool {b_short // 1024}K")
         if kind == "fleetopt":
             w_short = int(gamma * b_short)
             pools = (
@@ -676,7 +671,7 @@ class TopologySpec:
                          admit=math.inf),
             )
             return cls(kind=kind, pools=pools, models=models,
-                       accounting="fleetopt", b_short=b_short, gamma=gamma,
+                       accounting="fleetopt",
                        label=f"FleetOpt {w_short // 1024}K/g=1")
         if kind == "multipool":
             if not windows:
@@ -700,7 +695,6 @@ class TopologySpec:
                 overflow_to=names[i + 1] if i < len(ws) - 1 else None)
                 for i, w in enumerate(ws))
             return cls(kind=kind, pools=pools, models=models,
-                       b_short=b_short, gamma=gamma,
                        label=f"MultiPool{ws}")
         if kind in SEMANTIC_KINDS:
             if not 0.0 <= misroute_rate < 1.0:
@@ -736,7 +730,7 @@ class TopologySpec:
                        accounting="semantic", misroute_rate=misroute_rate,
                        detect_tokens=ESCALATION_DETECT_TOKENS,
                        misroute_seed=misroute_seed,
-                       flip=("small", "large"), b_short=b_short, gamma=g,
+                       flip=("small", "large"),
                        label=f"Semantic {b_short // 1024}K/g={g:g}"
                              + (f"/mr={misroute_rate:g}"
                                 if misroute_rate else ""))
@@ -759,7 +753,7 @@ class TopologySpec:
                     role=dec_role, window=w, profile=profile,
                     evict_on_overflow=nxt is not None, overflow_to=nxt))
             return cls(kind=kind, pools=tuple(pools), models=models,
-                       accounting="disagg", b_short=b_short, gamma=gamma,
+                       accounting="disagg",
                        label=f"Disagg{'+FleetOpt' if split else ''}")
         raise ValueError(kind)
 
@@ -776,5 +770,5 @@ def plan_roles(plan: FleetReport) -> List[str]:
         missing = [p.name for p in pools if not p.role]
         raise ValueError(
             f"plan pools {missing} carry no router role — provision fleets"
-            f" through core.topospec.TopologySpec (build_topology does)")
+            f" through core.topospec.TopologySpec.build")
     return roles

@@ -123,8 +123,8 @@ class Workload:
 # `DiurnalProfile` is a periodic piecewise-linear rate envelope r(t) over
 # hourly control points, normalised so the *peak* control point is 1.0 —
 # `peak_rate` then has the same meaning as `Workload.arrival_rate` at the
-# busiest instant, which is exactly the rate `provision()`/`size_to_slo`
-# size for.  Arrivals are sampled *exactly* (no thinning rejection noise)
+# busiest instant, which is exactly the rate `provision()` and
+# `size_to_slo_spec` size for.  Arrivals are sampled *exactly* (no thinning rejection noise)
 # by time-rescaling: unit-rate exponential gaps are cumsummed and mapped
 # through the inverse of the cumulative rate L(t) = integral r, which is
 # piecewise quadratic and invertible in closed form per segment.

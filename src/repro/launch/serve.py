@@ -31,8 +31,7 @@ def build_router(cfg, params, policy: str, *, b_short: int, window_long: int,
     if policy == "homo":
         pools = {"long": PoolEngine(cfg, params, window=window_long,
                                     profile=profile, n_slots=4, name="long")}
-        return ContextRouter(pools, RouterPolicy(
-            kind="homo", ladder=[("long", math.inf)]))
+        return ContextRouter(pools, RouterPolicy(ladder=[("long", math.inf)]))
     pools = {
         "short": PoolEngine(cfg, params, window=2 * b_short, profile=profile,
                             n_slots=16, name="short"),
@@ -45,10 +44,10 @@ def build_router(cfg, params, policy: str, *, b_short: int, window_long: int,
     boundary = float(b_short) if policy == "two_pool" \
         else float(int(2.0 * b_short))
     return ContextRouter(pools, RouterPolicy(
-        kind=policy, b_short=b_short, gamma=2.0, p99_output=p99_output,
+        ladder=[("short", boundary), ("long", math.inf)],
+        p99_output=p99_output,
         metric_kind="prompt_plus_p99" if policy == "two_pool"
-        else "predicted_total",
-        ladder=[("short", boundary), ("long", math.inf)]))
+        else "predicted_total"))
 
 
 def main() -> None:

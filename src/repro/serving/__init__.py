@@ -3,12 +3,11 @@ from .energy import EnergyMeter, MeterBank, conservation_violations
 from .engine import (DrainTruncatedError, PoolEngine, resolve_prefill_chunk,
                      scaled_prefill_chunk)
 from .fleetsim import (FleetSim, PoolGroup, PoolSummary, SimVsAnalytical,
-                       analytical_decode_tok_per_watt, build_topology,
-                       prepare_spec, prepare_topology, run_fleet_grid,
-                       simulate_spec, simulate_topology, trace_requests)
+                       analytical_decode_tok_per_watt, prepare_spec,
+                       run_fleet_grid, simulate_spec, trace_requests)
 from .models import ModelBinding, ModelProfileRegistry
 from .request import Request, sample_diurnal_trace, synthetic_requests
-from .router import SEMANTIC_KINDS, ContextRouter, RouterPolicy
+from .router import ContextRouter, RouterPolicy
 from .soa import BatchedPoolEngine
 from .telemetry import (TraceRecorder, build_timeline, phase_totals,
                         reconcile_energy, to_perfetto)
@@ -19,10 +18,8 @@ __all__ = ["EnergyMeter", "MeterBank", "PoolEngine", "BatchedPoolEngine",
            "Request", "synthetic_requests", "sample_diurnal_trace",
            "Autoscaler", "AutoscalePolicy", "InstanceSchedule",
            "ContextRouter", "RouterPolicy", "FleetSim", "PoolGroup",
-           "PoolSummary",
-           "SimVsAnalytical", "analytical_decode_tok_per_watt",
-           "build_topology", "simulate_topology", "simulate_spec",
+           "PoolSummary", "SimVsAnalytical",
+           "analytical_decode_tok_per_watt", "simulate_spec",
            "trace_requests", "ModelBinding", "ModelProfileRegistry",
-           "SEMANTIC_KINDS", "DrainTruncatedError", "resolve_prefill_chunk",
-           "scaled_prefill_chunk", "prepare_topology", "prepare_spec",
-           "run_fleet_grid"]
+           "DrainTruncatedError", "resolve_prefill_chunk",
+           "scaled_prefill_chunk", "prepare_spec", "run_fleet_grid"]

@@ -55,7 +55,6 @@ from __future__ import annotations
 
 import copy
 import dataclasses
-import math
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -65,7 +64,6 @@ from repro.core.timeline import EV_ARRIVE, EV_ROUTE
 from repro.core.disagg import HANDOFF_J_PER_BYTE, INTERCONNECT_BPS
 from repro.core.fleet import FleetReport, PoolOverride
 from repro.core.modelspec import ModelSpec
-from repro.core.profiles import BaseProfile
 from repro.core.routing import LONG_WINDOW
 from repro.core.topospec import TopologySpec, plan_roles
 from repro.core.workloads import Workload
@@ -106,37 +104,6 @@ def trace_requests(workload: Workload, n: int, *, seed: int = 0,
         # actual sampled output (core.routing.FleetOpt's assumption)
         predicted_output=mean_out)
         for i, (p, o, t) in enumerate(trace)]
-
-
-def build_topology(kind: str, workload: Workload, profile: BaseProfile,
-                   model: ModelSpec, *, b_short: int = 4096,
-                   gamma: float = 2.0, long_window: int = LONG_WINDOW,
-                   windows: Optional[Sequence[int]] = None,
-                   pool_overrides: Optional[Dict[str, PoolOverride]] = None,
-                   small_model: Optional[ModelSpec] = None,
-                   small_profile: Optional[BaseProfile] = None,
-                   misroute_rate: float = 0.0,
-                   dispatch_ms: float = 0.0,
-                   misroute_seed: int = 0,
-                   ) -> Tuple[RouterPolicy, FleetReport, ModelProfileRegistry]:
-    """(router policy, analytical sizing plan, model registry) for one §4
-    topology, a K >= 3 `core.multipool` ladder (`kind="multipool"`, pass
-    `windows`), or a model-heterogeneous kind — the same provisioning the
-    simulator instantiates and the prediction it is measured against.
-    `pool_overrides` layers per-role SLO recalibrations (core.slo) on the
-    closed-form plan.
-
-    This is a thin legacy-kind front end: the kind string compiles to a
-    `core.topospec.TopologySpec` (the declarative IR every layer reads —
-    DESIGN.md §12) and everything is derived from the spec.  Build the
-    spec directly (`TopologySpec.from_kind` or by hand) to keep it —
-    e.g. for `core.topo_search.optimize_topology`."""
-    spec = TopologySpec.from_kind(
-        kind, profile, model, b_short=b_short, gamma=gamma,
-        long_window=long_window, windows=windows, small_model=small_model,
-        small_profile=small_profile, misroute_rate=misroute_rate,
-        dispatch_ms=dispatch_ms, misroute_seed=misroute_seed)
-    return spec.build(workload, pool_overrides=pool_overrides)
 
 
 @dataclasses.dataclass
@@ -428,8 +395,7 @@ class FleetSim:
                 "FleetSim needs a spec-compiled policy: every pool's wiring"
                 " (roles, eviction, overflow/escalation/handoff edges) is"
                 " read from policy.spec — build the topology through"
-                " core.topospec.TopologySpec (from_kind / build) or"
-                " serving.fleetsim.build_topology")
+                " core.topospec.TopologySpec (from_kind / build)")
         self.spec = spec
         role_names = plan_roles(plan)
         roles = list(zip(role_names, pools))
@@ -909,33 +875,6 @@ def prepare_spec(spec: TopologySpec, workload: Workload, *,
     return sim, reqs, plan
 
 
-def prepare_topology(kind: str, workload: Workload, profile: BaseProfile,
-                     model: ModelSpec, *, b_short: int = 4096,
-                     gamma: float = 2.0,
-                     n_requests: int = 4000, seed: int = 0,
-                     arrival_rate: Optional[float] = None,
-                     prefill_chunk: int = 512,
-                     windows: Optional[Sequence[int]] = None,
-                     pool_overrides: Optional[Dict[str, PoolOverride]] = None,
-                     small_model: Optional[ModelSpec] = None,
-                     small_profile: Optional[BaseProfile] = None,
-                     misroute_rate: float = 0.0,
-                     dispatch_ms: float = 0.0,
-                     long_window: int = LONG_WINDOW,
-                     engine: str = "numpy"):
-    """Legacy-kind front end of `prepare_spec`: compile the kind string to
-    a `TopologySpec` and prepare it."""
-    spec = TopologySpec.from_kind(
-        kind, profile, model, b_short=b_short, gamma=gamma,
-        long_window=long_window, windows=windows, small_model=small_model,
-        small_profile=small_profile, misroute_rate=misroute_rate,
-        dispatch_ms=dispatch_ms, misroute_seed=seed)
-    return prepare_spec(spec, workload, n_requests=n_requests, seed=seed,
-                        arrival_rate=arrival_rate,
-                        prefill_chunk=prefill_chunk,
-                        pool_overrides=pool_overrides, engine=engine)
-
-
 def _sim_vs_analytical(sim: FleetSim, plan, kind: str,
                        workload_name: str,
                        report: Dict[str, dict]) -> SimVsAnalytical:
@@ -948,43 +887,16 @@ def _sim_vs_analytical(sim: FleetSim, plan, kind: str,
         report=report)
 
 
-def simulate_topology(kind: str, workload: Workload, profile: BaseProfile,
-                      model: ModelSpec, *, b_short: int = 4096,
-                      gamma: float = 2.0,
-                      n_requests: int = 4000, seed: int = 0,
-                      arrival_rate: Optional[float] = None,
-                      prefill_chunk: int = 512,
-                      windows: Optional[Sequence[int]] = None,
-                      pool_overrides: Optional[Dict[str, PoolOverride]] = None,
-                      small_model: Optional[ModelSpec] = None,
-                      small_profile: Optional[BaseProfile] = None,
-                      misroute_rate: float = 0.0,
-                      dispatch_ms: float = 0.0,
-                      long_window: int = LONG_WINDOW,
-                      engine: str = "numpy") -> SimVsAnalytical:
-    """Provision a topology analytically, then measure it end-to-end.
-    `engine="jax"` opts the pools into the jit/vmap drain loop
-    (serving.jax_engine); the default numpy engine is the bit-exact
-    oracle."""
-    sim, reqs, plan = prepare_topology(
-        kind, workload, profile, model, b_short=b_short, gamma=gamma,
-        n_requests=n_requests, seed=seed, arrival_rate=arrival_rate,
-        prefill_chunk=prefill_chunk, windows=windows,
-        pool_overrides=pool_overrides, small_model=small_model,
-        small_profile=small_profile, misroute_rate=misroute_rate,
-        dispatch_ms=dispatch_ms, long_window=long_window, engine=engine)
-    report = sim.run(reqs)
-    return _sim_vs_analytical(sim, plan, kind, workload.name, report)
-
-
 def simulate_spec(spec: TopologySpec, workload: Workload, *,
                   n_requests: int = 4000, seed: int = 0,
                   arrival_rate: Optional[float] = None,
                   prefill_chunk: int = 512,
                   pool_overrides: Optional[Dict[str, PoolOverride]] = None,
                   engine: str = "numpy") -> SimVsAnalytical:
-    """Measure an arbitrary `TopologySpec` end-to-end — `simulate_topology`
-    for specs that never had a kind string (hand-built or searched)."""
+    """Provision `spec` analytically, then measure it end-to-end.
+    `engine="jax"` opts the pools into the jit/vmap drain loop
+    (serving.jax_engine); the default numpy engine is the bit-exact
+    oracle."""
     sim, reqs, plan = prepare_spec(
         spec, workload, n_requests=n_requests, seed=seed,
         arrival_rate=arrival_rate, prefill_chunk=prefill_chunk,
@@ -1001,7 +913,7 @@ def run_fleet_grid(scenarios: List[Tuple[FleetSim, List[Request], object]],
     """Drain many prepared scenarios stage-by-stage so each topological
     stage's JAX pools compile and drain as **one** vmapped call.
 
-    `scenarios` is a list of `prepare_topology(...)` triples (every sim
+    `scenarios` is a list of `prepare_spec(...)` triples (every sim
     built with `engine="jax"`; numpy sims also work — they just drain
     serially inside the stage loop).  Stage k collects the k-th pool of
     every scenario, batch-drains the JAX ones via

@@ -16,7 +16,7 @@ engines run on, and the MoE dispatch floor used for per-iteration energy
 roofline — see `core.moe.with_dispatch_floor` — so time and energy can
 never disagree; the binding's `dispatch_ms` only labels the share).
 
-`serving.fleetsim.build_topology` constructs the registry next to the
+`core.topospec.TopologySpec.build` constructs the registry next to the
 router policy and sizing plan; `FleetSim` consumes it when instantiating
 engines.  Homogeneous topologies get a registry with only a default
 binding, so nothing changes for them.

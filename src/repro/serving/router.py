@@ -45,10 +45,9 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from repro.core.routing import ESCALATION_DETECT_TOKENS
-from repro.core.topospec import SEMANTIC_KINDS  # noqa: F401  (re-export)
 
 from .request import Request
 
@@ -66,16 +65,11 @@ def _misroute_u(rid: int, seed: int) -> float:
 
 @dataclasses.dataclass
 class RouterPolicy:
-    # the TopologySpec kind that compiled this policy (a label — routing
-    # behaviour is fully determined by the explicit fields below)
-    kind: str
-    b_short: int = 4096
-    gamma: float = 2.0
+    # Ordered (role, admission boundary) ladder, compiled by
+    # `TopologySpec.policy`: route to the first role whose boundary >= the
+    # request's routing metric.
+    ladder: List[Tuple[str, float]]
     p99_output: int = 1024     # conservative prompt_plus_p99 margin
-    # Ordered (role, admission boundary) ladder — REQUIRED: every policy
-    # carries its ladder explicitly (compiled by `TopologySpec.policy`);
-    # the router never derives rungs from the kind string.
-    ladder: Optional[List[Tuple[str, float]]] = None
     # routing metric: "predicted_total" (prompt + E[output]) or
     # "prompt_plus_p99" (prompt + p99_output — conservative two_pool)
     metric_kind: str = "predicted_total"
@@ -94,20 +88,6 @@ class RouterPolicy:
     # wiring — overflow/escalation/handoff edges — from it)
     spec: Optional[object] = dataclasses.field(default=None, repr=False)
 
-    @property
-    def is_semantic(self) -> bool:
-        return self.flip is not None
-
-    def admission_ladder(self, roles: Sequence[str] = ()
-                         ) -> List[Tuple[str, float]]:
-        """Ordered (role, boundary) pairs; route to the first role whose
-        boundary >= the request's routing metric."""
-        if not self.ladder:
-            raise ValueError(
-                f"{self.kind} policy needs an explicit ladder — compile it"
-                f" via core.topospec.TopologySpec (from_kind / policy())")
-        return list(self.ladder)
-
     def metric(self, req: Request) -> float:
         """The routing metric: predicted total for overflow-capable
         topologies; prompt + p99(output) for conservative two_pool."""
@@ -125,7 +105,10 @@ class ContextRouter:
     def __init__(self, pools: Dict[str, object], policy: RouterPolicy):
         self.pools = pools
         self.policy = policy
-        ladder = policy.admission_ladder(list(pools))
+        ladder = list(policy.ladder)
+        if not ladder:
+            raise ValueError("RouterPolicy.ladder is empty: it needs at"
+                             " least one (role, boundary) rung")
         missing = [r for r, _ in ladder if r not in pools]
         assert not missing, (missing, sorted(pools))
         bounds = [b for _, b in ladder]
@@ -133,18 +116,17 @@ class ContextRouter:
             f"admission boundaries must be strictly ascending: {ladder}"
         assert math.isinf(bounds[-1]), \
             f"last ladder entry must admit everything: {ladder}"
+        self.ladder = ladder
 
     def route(self, req: Request) -> str:
-        # the ladder is re-derived per call so policy mutation (and the
-        # unknown-kind ValueError) behave as if routing were stateless
-        ladder = self.policy.admission_ladder(list(self.pools))
         m = self.policy.metric(req)
-        for name, boundary in ladder:
+        for name, boundary in self.ladder:
             if m <= boundary:
                 name = self._semantic_flip(req, name)
                 self.pools[name].submit(req)
                 return name
-        raise AssertionError(f"no ladder entry admits metric {m}: {ladder}")
+        raise AssertionError(
+            f"no ladder entry admits metric {m}: {self.ladder}")
 
     def _semantic_flip(self, req: Request, nominal: str) -> str:
         """SemanticRouter error channel: flip the classifier's decision

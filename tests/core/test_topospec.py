@@ -30,9 +30,9 @@ WORKLOADS = (AZURE, LMSYS, AGENT)
 
 
 def _legacy_twin(kind, **kw):
-    """The analytical provisioner the legacy `build_topology` constructed
-    for each kind (its serving-twin conventions: fleetopt/disagg route
-    and serve at W = int(gamma * b_short))."""
+    """The analytical provisioner each kind's `TopologySpec.from_kind`
+    compilation must reproduce (its serving-twin conventions:
+    fleetopt/disagg route and serve at W = int(gamma * b_short))."""
     b_short = kw.get("b_short", 4096)
     gamma = kw.get("gamma", 2.0)
     dispatch_ms = kw.get("dispatch_ms", 0.0)
@@ -307,24 +307,22 @@ def test_validate_hol_and_dispatch_and_window():
         _spec([_pool("a", phase="warp")])
 
 
-def test_from_kind_legacy_errors_preserved():
-    with pytest.raises(ValueError, match="misroute_rate only applies"):
-        TopologySpec.from_kind("fleetopt", PROF, MODEL, misroute_rate=0.1)
-    with pytest.raises(ValueError, match="dispatch_ms only applies"):
-        TopologySpec.from_kind("homo", PROF, MODEL, dispatch_ms=2.0)
-    with pytest.raises(ValueError, match="needs an ascending"):
-        TopologySpec.from_kind("multipool", PROF, MODEL)
-    with pytest.raises(ValueError, match="strictly ascending"):
-        TopologySpec.from_kind("multipool", PROF, MODEL,
-                               windows=(8192, 4096))
-    with pytest.raises(ValueError, match="collide"):
-        TopologySpec.from_kind("multipool", PROF, MODEL,
-                               windows=(4096, 4100, 65536))
-    with pytest.raises(ValueError, match="gamma must be"):
-        TopologySpec.from_kind("multipool", PROF, MODEL,
-                               windows=(4096, 65536), gamma=0.5)
-    with pytest.raises(ValueError):
-        TopologySpec.from_kind("nope", PROF, MODEL)
+@pytest.mark.parametrize("kind,kw,match", [
+    ("fleetopt", dict(misroute_rate=0.1), "misroute_rate only applies"),
+    ("homo", dict(dispatch_ms=2.0), "dispatch_ms only applies"),
+    ("multipool", {}, "needs an ascending"),
+    ("multipool", dict(windows=(8192, 4096)), "strictly ascending"),
+    ("multipool", dict(windows=(4096, 4100, 65536)), "collide"),
+    ("multipool", dict(windows=(4096, 65536), gamma=0.5), "gamma must be"),
+    ("nope", dict(b_short=4096), "nope"),
+], ids=["misroute-non-semantic", "dispatch-non-moe", "multipool-no-windows",
+        "multipool-descending", "multipool-collide", "multipool-gamma",
+        "unknown-kind"])
+def test_from_kind_legacy_errors_preserved(kind, kw, match):
+    """`from_kind` is the one kind-dispatch site, so it is also where an
+    unknown kind or a kind's bad arguments are refused."""
+    with pytest.raises(ValueError, match=match):
+        TopologySpec.from_kind(kind, PROF, MODEL, **kw)
 
 
 # --- derived facts -------------------------------------------------------

@@ -10,9 +10,10 @@ import pytest
 from repro.core.disagg import HANDOFF_J_PER_BYTE
 from repro.core.modelspec import LLAMA31_70B
 from repro.core.profiles import H100_LLAMA70B
+from repro.core.topospec import TopologySpec
 from repro.core.workloads import AZURE
 from repro.serving import (EnergyMeter, FleetSim, PoolEngine, Request,
-                           build_topology, simulate_topology)
+                           simulate_spec)
 
 STREAMED = LLAMA31_70B.streamed_params
 
@@ -125,13 +126,12 @@ def test_charge_handoff_prorates_measurement_window():
 # --- router / topology wiring -------------------------------------------
 
 def test_disagg_topology_routes_into_prefill_pools():
-    policy, plan, _registry = build_topology(
-        "disagg_fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-        b_short=4096, gamma=2.0)
+    policy, plan, _registry = TopologySpec.from_kind(
+        "disagg_fleetopt", H100_LLAMA70B, LLAMA31_70B, b_short=4096,
+        gamma=2.0).build(AZURE)
     roles = [p.name for p in sorted(plan.pools, key=lambda p: p.window)]
     assert roles == ["prefill-8K", "decode-8K", "prefill-64K", "decode-64K"]
-    ladder = policy.admission_ladder(roles)
-    assert ladder == [("prefill-8K", 8192.0), ("prefill-64K", float("inf"))]
+    assert policy.ladder == [("prefill-8K", 8192.0), ("prefill-64K", float("inf"))]
     sim = FleetSim(policy, plan, model=LLAMA31_70B)
     assert sim.handoff_to == {"prefill-8K": "decode-8K",
                               "prefill-64K": "decode-64K"}
@@ -144,9 +144,9 @@ def test_disagg_overflow_reprefills_in_long_slice():
     """disagg_fleetopt overflow chain: a mispredicted request evicted from
     decode-8K re-prefills in prefill-64K (its KV was dropped) and finishes
     in decode-64K — two KV handoffs, one migration."""
-    policy, plan, _registry = build_topology(
-        "disagg_fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-        b_short=4096, gamma=2.0)
+    policy, plan, _registry = TopologySpec.from_kind(
+        "disagg_fleetopt", H100_LLAMA70B, LLAMA31_70B, b_short=4096,
+        gamma=2.0).build(AZURE)
     sim = FleetSim(policy, plan, model=LLAMA31_70B)
     chain = _req(0, 900, 8000, pred=100)    # predicted 1000 -> short slice
     rep = sim.run([chain])
@@ -163,9 +163,10 @@ def test_disagg_overflow_reprefills_in_long_slice():
 
 @pytest.fixture(scope="module")
 def disagg_cells():
-    return {kind: simulate_topology(
-        kind, AZURE, H100_LLAMA70B, LLAMA31_70B,
-        b_short=4096, n_requests=8000, seed=0)
+    return {kind: simulate_spec(
+        TopologySpec.from_kind(kind, H100_LLAMA70B, LLAMA31_70B,
+                               b_short=4096),
+        AZURE, n_requests=8000, seed=0)
         for kind in ("disagg", "disagg_fleetopt")}
 
 
@@ -216,9 +217,9 @@ def test_prefill_role_latency_includes_downstream_metrics(disagg_cells):
     pool filled in after the prefill pool drained (regression pin: the
     SoA summaries are snapshotted per pool at drain time, and prefill
     pools' percentiles must be refreshed once the fleet finishes)."""
-    from repro.serving import FleetSim, build_topology, trace_requests
-    policy, plan, reg = build_topology("disagg", AZURE, H100_LLAMA70B,
-                                       LLAMA31_70B, b_short=4096)
+    from repro.serving import trace_requests
+    policy, plan, reg = TopologySpec.from_kind(
+        "disagg", H100_LLAMA70B, LLAMA31_70B, b_short=4096).build(AZURE)
     sim = FleetSim(policy, plan, registry=reg)
     sim.run(trace_requests(AZURE, 400, seed=2))
     for role, lat in sim.latency_by_role().items():

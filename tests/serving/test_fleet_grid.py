@@ -13,8 +13,9 @@ import pytest
 jax = pytest.importorskip("jax")
 
 from benchmarks import fleet_grid_bench as gb          # noqa: E402
+from repro.core.topospec import TopologySpec           # noqa: E402
 from repro.core.workloads import AZURE                 # noqa: E402
-from repro.serving import prepare_topology, run_fleet_grid  # noqa: E402
+from repro.serving import prepare_spec, run_fleet_grid  # noqa: E402
 
 pytestmark = pytest.mark.gridsmoke
 
@@ -36,9 +37,9 @@ def _slice():
 
 def _measure(engine):
     chunk = _slice()
-    scenarios = [prepare_topology(kind, AZURE, prof, mdl,
-                                  n_requests=N_REQUESTS, seed=0,
-                                  engine=engine, **kw)
+    scenarios = [prepare_spec(TopologySpec.from_kind(kind, prof, mdl, **kw),
+                              AZURE, n_requests=N_REQUESTS, seed=0,
+                              engine=engine)
                  for _, kind, prof, mdl, kw in chunk]
     floors = gb.SHAPE_CLASSES if engine == "jax" else None
     out = {}

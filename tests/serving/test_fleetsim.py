@@ -10,10 +10,11 @@ import pytest
 
 from repro.core.modelspec import LLAMA31_70B
 from repro.core.profiles import H100_LLAMA70B
+from repro.core.topospec import TopologySpec
 from repro.core.workloads import AGENT, AZURE
 from repro.serving import (ContextRouter, EnergyMeter, FleetSim, PoolEngine,
-                           PoolGroup, Request, RouterPolicy, build_topology,
-                           simulate_topology, trace_requests)
+                           PoolGroup, Request, RouterPolicy, simulate_spec,
+                           trace_requests)
 
 STREAMED = LLAMA31_70B.streamed_params
 
@@ -99,9 +100,10 @@ def test_overflow_eviction_backs_out_wasted_tokens():
 
 @pytest.fixture(scope="module")
 def azure_cells():
-    return {kind: simulate_topology(
-        kind, AZURE, H100_LLAMA70B, LLAMA31_70B,
-        b_short=4096, n_requests=8000, seed=0)
+    return {kind: simulate_spec(
+        TopologySpec.from_kind(kind, H100_LLAMA70B, LLAMA31_70B,
+                               b_short=4096),
+        AZURE, n_requests=8000, seed=0)
         for kind in ("homo", "fleetopt")}
 
 
@@ -135,9 +137,9 @@ def test_fleet_conservation_and_report_shape(azure_cells):
 def test_overflow_migration_end_to_end():
     """A tight gamma forces short-pool overflows; migrated requests must
     re-prefill in the long pool and every request still completes."""
-    cell = simulate_topology("fleetopt", AGENT, H100_LLAMA70B, LLAMA31_70B,
-                             b_short=8192, gamma=1.1, n_requests=1500,
-                             seed=1)
+    spec = TopologySpec.from_kind("fleetopt", H100_LLAMA70B, LLAMA31_70B,
+                                  b_short=8192, gamma=1.1)
+    cell = simulate_spec(spec, AGENT, n_requests=1500, seed=1)
     f = cell.report["fleet"]
     assert f["migrations"] > 0
     assert f["completed"] == 1500
@@ -150,9 +152,9 @@ def test_multipool_migration_chain_short_mid_long():
     """K = 3 ladder: a request whose actual total outgrows both the 2K and
     the 8K windows must migrate twice (pool-2K -> pool-8K -> pool-64K) and
     still complete in full."""
-    policy, plan, _registry = build_topology("multipool", AGENT, H100_LLAMA70B,
-                                  LLAMA31_70B, gamma=2.0,
-                                  windows=[2048, 8192, 65536])
+    policy, plan, _registry = TopologySpec.from_kind(
+        "multipool", H100_LLAMA70B, LLAMA31_70B, gamma=2.0,
+        windows=[2048, 8192, 65536]).build(AGENT)
     assert [p.name for p in sorted(plan.pools, key=lambda p: p.window)] \
         == ["pool-2K", "pool-8K", "pool-64K"]
     sim = FleetSim(policy, plan, model=LLAMA31_70B)
@@ -175,9 +177,9 @@ def test_multipool_migration_chain_short_mid_long():
 def test_multipool_end_to_end_on_trace():
     """A K = 3 plan runs a real trace through FleetSim: every request
     completes and each rung of the ladder serves traffic."""
-    cell = simulate_topology("multipool", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                             windows=[4096, 16384, 65536], n_requests=1000,
-                             seed=0)
+    spec = TopologySpec.from_kind("multipool", H100_LLAMA70B, LLAMA31_70B,
+                                  windows=[4096, 16384, 65536])
+    cell = simulate_spec(spec, AZURE, n_requests=1000, seed=0)
     f = cell.report["fleet"]
     assert f["completed"] == 1000
     roles = [r for r in cell.report if r != "fleet"]
@@ -210,8 +212,8 @@ def test_router_report_honors_measurement_window():
     lifetime totals are non-zero."""
     eng = PoolEngine(None, None, window=64, profile=H100_LLAMA70B,
                      n_slots=2, streamed_params=STREAMED)
-    router = ContextRouter({"only": eng}, RouterPolicy(
-        kind="homo", ladder=[("only", math.inf)]))
+    router = ContextRouter({"only": eng},
+                           RouterPolicy(ladder=[("only", math.inf)]))
     eng.meter.measure_t1 = 0.0
     rep = router.run([_req(i, 8, 6) for i in range(3)])
     assert eng.meter.tokens > 0
@@ -222,8 +224,8 @@ def test_router_report_honors_measurement_window():
 def test_router_and_fleetsim_agree_on_measured_tokens():
     """The two report paths count the same steady-state window — they can
     no longer disagree on identical runs (the PR-1 defect)."""
-    policy, plan, _registry = build_topology("fleetopt", AZURE, H100_LLAMA70B,
-                                  LLAMA31_70B, b_short=4096)
+    policy, plan, _registry = TopologySpec.from_kind(
+        "fleetopt", H100_LLAMA70B, LLAMA31_70B, b_short=4096).build(AZURE)
     sim = FleetSim(policy, plan, model=LLAMA31_70B)
     rep = sim.run(trace_requests(AZURE, 600, seed=2))
     router_rep = sim.router.report()
@@ -278,14 +280,6 @@ def test_boundary_straddling_prefill_prorated():
     m.charge_prefill(4096, streamed_params=STREAMED)
     assert m.m_prefill_joules == pytest.approx(0.5 * m.prefill_joules,
                                                rel=1e-9)
-
-
-def test_build_topology_rejects_unknown_kind():
-    with pytest.raises(ValueError):
-        build_topology("nope", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                       b_short=4096)
-    with pytest.raises(ValueError):   # multipool without a window ladder
-        build_topology("multipool", AZURE, H100_LLAMA70B, LLAMA31_70B)
 
 
 def test_trace_requests_clips_and_predicts():

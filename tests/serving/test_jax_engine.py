@@ -426,13 +426,14 @@ def test_select_lookup_matches_gather_bit_for_bit(kind, shape):
 
 
 def test_jax_fleet_matches_numpy_fleet_seed_numbers():
-    """End-to-end anchor: `simulate_topology(engine="jax")` reproduces the
+    """End-to-end anchor: `simulate_spec(engine="jax")` reproduces the
     numpy fleet's committed seed cell (Azure fleetopt, 1000 requests,
     seed 0) to the rounding the baseline records."""
-    from repro.serving import simulate_topology
-    cell = simulate_topology("fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                             b_short=4096, n_requests=1000, seed=0,
-                             engine="jax")
+    from repro.core.topospec import TopologySpec
+    from repro.serving import simulate_spec
+    spec = TopologySpec.from_kind("fleetopt", H100_LLAMA70B, LLAMA31_70B,
+                                  b_short=4096)
+    cell = simulate_spec(spec, AZURE, n_requests=1000, seed=0, engine="jax")
     f = cell.report["fleet"]
     assert f["completed"] == 1000
     assert round(cell.sim_decode_tok_per_watt, 2) == 5.66

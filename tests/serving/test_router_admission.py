@@ -35,9 +35,7 @@ def _router(kind, *, b_short=4096, gamma=2.0, **kw):
         ladder = [("short", boundary), ("long", math.inf)]
     if kind == "two_pool":
         kw.setdefault("metric_kind", "prompt_plus_p99")
-    return ContextRouter(pools, RouterPolicy(kind=kind, b_short=b_short,
-                                             gamma=gamma, ladder=ladder,
-                                             **kw))
+    return ContextRouter(pools, RouterPolicy(ladder=ladder, **kw))
 
 
 def test_homo_routes_everything_to_the_single_pool():
@@ -83,13 +81,6 @@ def test_fleetopt_routes_on_prediction_not_actual_length():
     assert r.route(_req(1, 30, 5, predicted=40)) == "long"
 
 
-def test_unknown_policy_kind_raises():
-    r = _router("homo")
-    r.policy = RouterPolicy(kind="nope")
-    with pytest.raises(ValueError):
-        r.route(_req(0, 1, 1))
-
-
 # --- K >= 3 admission ladders (paper §10.3 via core.multipool) -----------
 
 def _k3_pools():
@@ -99,8 +90,7 @@ def _k3_pools():
 
 def _k3_router():
     ladder = [("p0", 64.0), ("p1", 256.0), ("p2", math.inf)]
-    return ContextRouter(_k3_pools(),
-                         RouterPolicy(kind="multipool", ladder=ladder))
+    return ContextRouter(_k3_pools(), RouterPolicy(ladder=ladder))
 
 
 def test_multipool_ladder_boundaries_are_exact():
@@ -119,25 +109,59 @@ def test_multipool_routes_on_prediction_not_actual_length():
     assert r.route(_req(1, 30, 5, predicted=400)) == "p2"
 
 
-def test_multipool_policy_requires_ladder():
-    with pytest.raises(ValueError):
-        ContextRouter({"p0": _pool("p0", 128)},
-                      RouterPolicy(kind="multipool"))
+class _Sink:
+    """Pool-shaped stand-in that records the rids submitted to it."""
+
+    def __init__(self):
+        self.rids = []
+
+    def submit(self, req):
+        self.rids.append(req.rid)
+
+
+def test_k3_ladder_routes_a_trace_to_the_first_admitting_rung():
+    """Every request of a trace lands on the first rung whose boundary
+    covers its predicted total — the kept ladder routes as a ladder
+    re-read per request would."""
+    rng = np.random.default_rng(7)
+    bounds = [64.0, 256.0, math.inf]
+    totals = np.concatenate([rng.integers(2, 600, 400), [64, 65, 256, 257]])
+    reqs = []
+    for rid, t in enumerate(totals):
+        plen = int(rng.integers(1, t))
+        reqs.append(_req(rid, plen, 1, predicted=int(t) - plen))
+    sinks = {"p0": _Sink(), "p1": _Sink(), "p2": _Sink()}
+    r = ContextRouter(sinks, RouterPolicy(ladder=list(zip(sinks, bounds))))
+    got = [r.route(q) for q in reqs]
+    want = [list(sinks)[i] for i in np.searchsorted(bounds, totals)]
+    assert got == want
+    assert {role: s.rids for role, s in sinks.items()} == {
+        role: [i for i, w in enumerate(want) if w == role] for role in sinks}
+    assert all(s.rids for s in sinks.values())
+
+
+@pytest.mark.parametrize("ladder,exc", [(None, TypeError), ([], ValueError)],
+                         ids=["missing", "empty"])
+def test_multipool_policy_requires_ladder(ladder, exc):
+    """A policy cannot be built without a ladder, and a router refuses an
+    empty one when it is constructed, not at the first route."""
+    with pytest.raises(exc):
+        policy = RouterPolicy() if ladder is None \
+            else RouterPolicy(ladder=ladder)
+        ContextRouter({"p0": _pool("p0", 128)}, policy)
 
 
 def test_ladder_must_ascend_and_terminate_infinite():
     pools = _k3_pools()
     with pytest.raises(AssertionError):   # descending boundaries
         ContextRouter(pools, RouterPolicy(
-            kind="multipool",
             ladder=[("p0", 256.0), ("p1", 64.0), ("p2", math.inf)]))
     with pytest.raises(AssertionError):   # last rung not infinite
         ContextRouter(pools, RouterPolicy(
-            kind="multipool", ladder=[("p0", 64.0), ("p1", 256.0)]))
+            ladder=[("p0", 64.0), ("p1", 256.0)]))
 
 
 def test_ladder_roles_must_exist():
     with pytest.raises(AssertionError):
         ContextRouter({"p0": _pool("p0", 128)},
-                      RouterPolicy(kind="multipool",
-                                   ladder=[("nope", math.inf)]))
+                      RouterPolicy(ladder=[("nope", math.inf)]))

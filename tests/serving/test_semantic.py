@@ -16,10 +16,11 @@ from repro.core.moe import moe_profile, with_dispatch_floor
 from repro.core.power import H100_POWER
 from repro.core.profiles import (B200_LLAMA70B_FLEET, H100_LLAMA70B,
                                  V5E_LLAMA70B)
+from repro.core.topospec import TopologySpec
 from repro.core.workloads import AZURE
 from repro.serving import (ContextRouter, EnergyMeter, FleetSim, PoolEngine,
-                           Request, RouterPolicy, build_topology,
-                           scaled_prefill_chunk, simulate_topology)
+                           Request, RouterPolicy, scaled_prefill_chunk,
+                           simulate_spec)
 
 STREAMED = LLAMA31_70B.streamed_params
 
@@ -43,8 +44,8 @@ def _pools():
 
 def test_semantic_routes_by_predicted_total_at_zero_misroute():
     r = ContextRouter(_pools(), RouterPolicy(
-        kind="semantic", b_short=64, flip=("small", "large"),
-        ladder=[("small", 64.0), ("large", math.inf)]))
+        ladder=[("small", 64.0), ("large", math.inf)],
+        flip=("small", "large")))
     assert r.route(_req(0, 32, 500, pred=32)) == "small"   # 64, inclusive
     assert r.route(_req(1, 33, 1, pred=32)) == "large"     # 65 > 64
     # zero misroute never flips or tags
@@ -55,10 +56,9 @@ def test_semantic_routes_by_predicted_total_at_zero_misroute():
 
 
 def test_misroute_flip_tags_only_large_into_small():
-    pol = RouterPolicy(kind="semantic", b_short=64, misroute_rate=0.5,
-                       detect_tokens=7, misroute_seed=3,
-                       flip=("small", "large"),
-                       ladder=[("small", 64.0), ("large", math.inf)])
+    pol = RouterPolicy(ladder=[("small", 64.0), ("large", math.inf)],
+                       misroute_rate=0.5, detect_tokens=7, misroute_seed=3,
+                       flip=("small", "large"))
     r = ContextRouter(_pools(), pol)
     tagged = flipped_large = 0
     for rid in range(400):
@@ -86,9 +86,8 @@ def test_misroute_draw_is_deterministic_and_nested():
     higher misroute rate flips a *superset* of a lower rate's requests —
     the property that makes the degradation sweep monotone."""
     def misrouted(rate):
-        pol = RouterPolicy(kind="semantic", b_short=64, misroute_rate=rate,
-                           flip=("small", "large"),
-                           ladder=[("small", 64.0), ("large", math.inf)])
+        pol = RouterPolicy(ladder=[("small", 64.0), ("large", math.inf)],
+                           misroute_rate=rate, flip=("small", "large"))
         r = ContextRouter(_pools(), pol)
         out = set()
         for rid in range(500):
@@ -153,9 +152,9 @@ def test_overflow_eviction_clears_escalation_tag():
 
 # --- ModelProfileRegistry wiring ----------------------------------------
 
-def test_build_topology_binds_models_per_role():
-    policy, plan, registry = build_topology(
-        "semantic", AZURE, H100_LLAMA70B, LLAMA31_70B, b_short=4096)
+def test_spec_build_binds_models_per_role():
+    policy, plan, registry = TopologySpec.from_kind(
+        "semantic", H100_LLAMA70B, LLAMA31_70B, b_short=4096).build(AZURE)
     assert registry.for_role("small").model is LLAMA31_8B
     assert registry.for_role("large").model is LLAMA31_70B
     assert registry.heterogeneous
@@ -173,19 +172,20 @@ def test_build_topology_binds_models_per_role():
 
 
 def test_semantic_fleetopt_gets_overflow_headroom():
-    _, plan, _ = build_topology("semantic_fleetopt", AZURE, H100_LLAMA70B,
-                                LLAMA31_70B, b_short=4096, gamma=2.0)
+    _, plan, _ = TopologySpec.from_kind(
+        "semantic_fleetopt", H100_LLAMA70B, LLAMA31_70B, b_short=4096,
+        gamma=2.0).build(AZURE)
     small = min(plan.pools, key=lambda p: p.window)
     assert small.window == 8192          # serve at gamma * b_short
 
 
 def test_misroute_and_dispatch_args_are_kind_checked():
     with pytest.raises(ValueError):
-        build_topology("fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                       misroute_rate=0.1)
+        TopologySpec.from_kind("fleetopt", H100_LLAMA70B, LLAMA31_70B,
+                               misroute_rate=0.1)
     with pytest.raises(ValueError):
-        build_topology("semantic", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                       dispatch_ms=2.0)
+        TopologySpec.from_kind("semantic", H100_LLAMA70B, LLAMA31_70B,
+                               dispatch_ms=2.0)
 
 
 # --- MoE dispatch floor --------------------------------------------------
@@ -205,8 +205,8 @@ def test_with_dispatch_floor_extends_tau_and_meter_attributes_it():
 
 def test_moe_pool_engines_stream_active_params():
     prof = moe_profile(QWEN3_235B_A22B, H100, H100_POWER, tp=8)
-    policy, plan, registry = build_topology(
-        "moe_pool", AZURE, prof, QWEN3_235B_A22B, dispatch_ms=2.0)
+    policy, plan, registry = TopologySpec.from_kind(
+        "moe_pool", prof, QWEN3_235B_A22B, dispatch_ms=2.0).build(AZURE)
     assert registry.default.dispatch_ms == 2.0
     (pool,) = plan.pools
     assert pool.profile.roofline.w_ms == pytest.approx(
@@ -230,8 +230,8 @@ def test_prefill_chunk_scales_with_memory_bandwidth():
 
 
 def test_fleetsim_applies_scaled_chunk_per_pool():
-    policy, plan, registry = build_topology(
-        "homo", AZURE, B200_LLAMA70B_FLEET, LLAMA31_70B)
+    policy, plan, registry = TopologySpec.from_kind(
+        "homo", B200_LLAMA70B_FLEET, LLAMA31_70B).build(AZURE)
     sim = FleetSim(policy, plan, registry=registry, prefill_chunk=512)
     assert sim.groups["homo"].prefill_chunk == \
         scaled_prefill_chunk(B200_LLAMA70B_FLEET, 512)
@@ -242,13 +242,14 @@ def test_fleetsim_applies_scaled_chunk_per_pool():
 @pytest.fixture(scope="module")
 def hetero_cells():
     prof_moe = moe_profile(QWEN3_235B_A22B, H100, H100_POWER, tp=8)
-    cells = {kind: simulate_topology(
-        kind, AZURE, H100_LLAMA70B, LLAMA31_70B,
-        b_short=4096, n_requests=8000, seed=0)
+    cells = {kind: simulate_spec(
+        TopologySpec.from_kind(kind, H100_LLAMA70B, LLAMA31_70B,
+                               b_short=4096, misroute_seed=0),
+        AZURE, n_requests=8000, seed=0)
         for kind in ("semantic", "semantic_fleetopt")}
-    cells["moe_pool"] = simulate_topology(
-        "moe_pool", AZURE, prof_moe, QWEN3_235B_A22B,
-        n_requests=8000, seed=0)
+    cells["moe_pool"] = simulate_spec(
+        TopologySpec.from_kind("moe_pool", prof_moe, QWEN3_235B_A22B),
+        AZURE, n_requests=8000, seed=0)
     return cells
 
 
@@ -271,8 +272,9 @@ def test_zero_misroute_fleet_has_no_escalations(hetero_cells):
 def test_semantic_beats_homogeneous_70b(hetero_cells):
     """The §5.1 lever measured: serving the short 89% of Azure traffic
     with an 8B model beats the homogeneous 70B fleet on tok/W."""
-    homo = simulate_topology("homo", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                             n_requests=8000, seed=0)
+    homo = simulate_spec(
+        TopologySpec.from_kind("homo", H100_LLAMA70B, LLAMA31_70B),
+        AZURE, n_requests=8000, seed=0)
     sem = hetero_cells["semantic"]
     assert sem.sim_decode_tok_per_watt > 2.0 * homo.sim_decode_tok_per_watt
 
@@ -288,9 +290,10 @@ def test_misroute_sweep_monotone_and_never_double_counted():
     rates = (0.0, 0.1, 0.2, 0.35)
     all_in, decode, esc = [], [], []
     for mr in rates:
-        cell = simulate_topology(
-            "semantic_fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-            b_short=4096, n_requests=2500, seed=0, misroute_rate=mr)
+        spec = TopologySpec.from_kind(
+            "semantic_fleetopt", H100_LLAMA70B, LLAMA31_70B, b_short=4096,
+            misroute_rate=mr, misroute_seed=0)
+        cell = simulate_spec(spec, AZURE, n_requests=2500, seed=0)
         f = cell.report["fleet"]
         assert f["completed"] == 2500
         all_in.append(cell.sim_tok_per_watt)
@@ -303,9 +306,9 @@ def test_misroute_sweep_monotone_and_never_double_counted():
 
 
 def test_escalated_tokens_conserved_end_to_end():
-    policy, plan, registry = build_topology(
-        "semantic_fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-        b_short=4096, misroute_rate=0.25, misroute_seed=0)
+    policy, plan, registry = TopologySpec.from_kind(
+        "semantic_fleetopt", H100_LLAMA70B, LLAMA31_70B, b_short=4096,
+        misroute_rate=0.25, misroute_seed=0).build(AZURE)
     sim = FleetSim(policy, plan, registry=registry, rng_seed=0)
     from repro.serving import trace_requests
     reqs = trace_requests(AZURE, 1200, seed=0)

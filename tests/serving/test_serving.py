@@ -85,16 +85,14 @@ def test_router_policies(small_model):
         "long": PoolEngine(cfg, params, window=128, profile=H100_LLAMA70B,
                            n_slots=2, name="long")}
     r_fo = ContextRouter(mk(), RouterPolicy(
-        kind="fleetopt", b_short=16, gamma=2.0,
         ladder=[("short", 32.0), ("long", math.inf)]))
     short_req = Request(rid=0, prompt=np.arange(10), max_new_tokens=8)
     long_req = Request(rid=1, prompt=np.arange(100), max_new_tokens=8)
     assert r_fo.route(short_req) == "short"     # 18 <= 2*16
     assert r_fo.route(long_req) == "long"
     r_tp = ContextRouter(mk(), RouterPolicy(
-        kind="two_pool", b_short=16, p99_output=10,
-        metric_kind="prompt_plus_p99",
-        ladder=[("short", 16.0), ("long", math.inf)]))
+        ladder=[("short", 16.0), ("long", math.inf)], p99_output=10,
+        metric_kind="prompt_plus_p99"))
     assert r_tp.route(Request(rid=2, prompt=np.arange(5),
                               max_new_tokens=8)) == "short"
     assert r_tp.route(Request(rid=3, prompt=np.arange(10),
@@ -115,7 +113,7 @@ def test_two_pool_beats_homo_on_energy(small_model):
     homo = ContextRouter(
         {"only": PoolEngine(cfg, params, window=128,
                             profile=H100_LLAMA70B, n_slots=4, name="only")},
-        RouterPolicy(kind="homo", ladder=[("only", math.inf)]))
+        RouterPolicy(ladder=[("only", math.inf)]))
     rep_h = homo.run([dataclasses.replace(r) for r in reqs], max_iters=500)
 
     routed = ContextRouter(
@@ -123,8 +121,7 @@ def test_two_pool_beats_homo_on_energy(small_model):
                              profile=H100_LLAMA70B, n_slots=16, name="short"),
          "long": PoolEngine(cfg, params, window=128,
                             profile=H100_LLAMA70B, n_slots=4, name="long")},
-        RouterPolicy(kind="fleetopt", b_short=8, gamma=2.0,
-                     ladder=[("short", 16.0), ("long", math.inf)]))
+        RouterPolicy(ladder=[("short", 16.0), ("long", math.inf)]))
     rep_r = routed.run([dataclasses.replace(r) for r in reqs], max_iters=500)
 
     assert rep_r["fleet"]["tok_per_watt"] > rep_h["fleet"]["tok_per_watt"]

@@ -7,7 +7,8 @@ geometric step's overshoot), the e2e_p99_s constraint, the K >= 3
 multipool path and the already-compliant fast path."""
 import pytest
 
-from repro.core import AZURE, H100_LLAMA70B, ladder_windows, size_to_slo
+from repro.core import (AZURE, H100_LLAMA70B, TopologySpec, ladder_windows,
+                        size_to_slo_spec)
 from repro.core.modelspec import LLAMA31_70B
 from repro.core.profiles import B200_LLAMA70B_FLEET
 from repro.core.slo import SLOSpec
@@ -15,8 +16,9 @@ from repro.core.slo import SLOSpec
 
 @pytest.fixture(scope="module")
 def fleetopt_slo():
-    return size_to_slo("fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                       b_short=4096, n_requests=2000, seed=0)
+    spec = TopologySpec.from_kind("fleetopt", H100_LLAMA70B, LLAMA31_70B,
+                                  b_short=4096)
+    return size_to_slo_spec(spec, AZURE, n_requests=2000, seed=0)
 
 
 def test_slo_loop_converges(fleetopt_slo):
@@ -60,8 +62,9 @@ def test_slo_calibrates_effective_prefill_mfu(fleetopt_slo):
 
 
 def test_slo_multipool_k3_end_to_end():
-    r = size_to_slo("multipool", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                    windows=ladder_windows(3), n_requests=1500, seed=0)
+    spec = TopologySpec.from_kind("multipool", H100_LLAMA70B, LLAMA31_70B,
+                                  windows=ladder_windows(3))
+    r = size_to_slo_spec(spec, AZURE, n_requests=1500, seed=0)
     assert r.compliant
     assert r.ttft_p99_s <= 0.5
     roles = [k for k in r.report if k != "fleet"]
@@ -88,8 +91,9 @@ def test_slo_trim_phase_shaves_overshoot(fleetopt_slo):
 
 
 def test_slo_trim_can_be_disabled():
-    r = size_to_slo("fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                    b_short=4096, n_requests=2000, seed=0, trim=False)
+    spec = TopologySpec.from_kind("fleetopt", H100_LLAMA70B, LLAMA31_70B,
+                                  b_short=4096)
+    r = size_to_slo_spec(spec, AZURE, n_requests=2000, seed=0, trim=False)
     assert r.compliant
     assert r.trim_rounds == 0 and not r.trimmed
     assert r.plan.instances == sum(r.rounds[-1].instances.values())
@@ -100,9 +104,10 @@ def test_slo_e2e_constraint_attributes_to_decoding_pool():
     decoded the request (capacity elsewhere cannot buy e2e latency).  The
     2 s target sits below the long tail's service floor, so the pin is
     the recorded measurement and the attribution, not compliance."""
-    r = size_to_slo("fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                    b_short=4096, n_requests=1500, seed=0, max_rounds=2,
-                    slo=SLOSpec(ttft_p99_s=0.5, e2e_p99_s=2.0))
+    spec = TopologySpec.from_kind("fleetopt", H100_LLAMA70B, LLAMA31_70B,
+                                  b_short=4096)
+    r = size_to_slo_spec(spec, AZURE, n_requests=1500, seed=0, max_rounds=2,
+                         slo=SLOSpec(ttft_p99_s=0.5, e2e_p99_s=2.0))
     assert r.slo.e2e_p99_s == 2.0
     assert r.rounds[0].e2e_p99_s > 2.0
     assert sum(r.rounds[0].violators.values()) > 0
@@ -114,8 +119,8 @@ def test_slo_already_compliant_fleet_untouched():
     """B200 homo meets the SLO at the unconstrained sizing: the loop must
     terminate in one round at zero cost — and the trim phase must not
     touch a fleet that never grew."""
-    r = size_to_slo("homo", AZURE, B200_LLAMA70B_FLEET, LLAMA31_70B,
-                    n_requests=1500, seed=0)
+    spec = TopologySpec.from_kind("homo", B200_LLAMA70B_FLEET, LLAMA31_70B)
+    r = size_to_slo_spec(spec, AZURE, n_requests=1500, seed=0)
     assert r.compliant
     assert len(r.rounds) == 1
     assert r.instances_added == 0
@@ -128,8 +133,9 @@ def test_slo_disagg_grows_prefill_fleet_for_ttft():
     """Disaggregated serving: TTFT violations are attributed to the
     prefill pools (they drain the prompt), so the loop re-provisions the
     prefill fleet and leaves the decode fleet alone."""
-    r = size_to_slo("disagg_fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                    b_short=4096, n_requests=1500, seed=0)
+    spec = TopologySpec.from_kind("disagg_fleetopt", H100_LLAMA70B,
+                                  LLAMA31_70B, b_short=4096)
+    r = size_to_slo_spec(spec, AZURE, n_requests=1500, seed=0)
     assert r.compliant
     assert r.ttft_p99_s <= 0.5
     first, last = r.rounds[0].instances, r.rounds[-1].instances
@@ -149,9 +155,10 @@ def test_slo_semantic_and_moe_kinds_end_to_end():
     at 0.1 it alone overflows the budget and the SLO is service-time
     unattainable — see DESIGN.md §9), and the MoE pool with a 2 ms
     dispatch floor re-provisions into compliance."""
-    r = size_to_slo("semantic_fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                    b_short=4096, n_requests=1500, seed=0,
-                    misroute_rate=0.05)
+    spec = TopologySpec.from_kind("semantic_fleetopt", H100_LLAMA70B,
+                                  LLAMA31_70B, b_short=4096,
+                                  misroute_rate=0.05, misroute_seed=0)
+    r = size_to_slo_spec(spec, AZURE, n_requests=1500, seed=0)
     assert r.compliant and r.ttft_p99_s <= 0.5
     assert set(r.rounds[0].instances) == {"small", "large"}
     assert r.report["fleet"]["escalations"] > 0
@@ -161,8 +168,9 @@ def test_slo_semantic_and_moe_kinds_end_to_end():
     from repro.core.moe import moe_profile
     from repro.core.power import H100_POWER
     prof = moe_profile(QWEN3_235B_A22B, H100, H100_POWER, tp=8)
-    m = size_to_slo("moe_pool", AZURE, prof, QWEN3_235B_A22B,
-                    n_requests=1500, seed=0, dispatch_ms=2.0, trim=False)
+    spec = TopologySpec.from_kind("moe_pool", prof, QWEN3_235B_A22B,
+                                  dispatch_ms=2.0)
+    m = size_to_slo_spec(spec, AZURE, n_requests=1500, seed=0, trim=False)
     assert m.compliant and m.ttft_p99_s <= 0.5
     assert list(m.rounds[0].instances) == ["moe"]
     assert len(m.rounds) >= 2          # the dispatch floor forced growth
@@ -199,7 +207,7 @@ def test_slo_converges_identically_to_per_engine_loop(fleetopt_slo):
 
 
 def test_slo_measures_hol_inflation_and_feeds_it_back():
-    """ROADMAP gap closed: `size_to_slo` measures per-pool HOL queueing
+    """ROADMAP gap closed: `size_to_slo_spec` measures per-pool HOL queueing
     (occupied-slot population vs the hol=1 Little's-law population) and
     drives `PoolOverride.hol_inflation` from it.  On a prefill-heavy
     workload — slots held through long prompt drains the decode-
@@ -211,8 +219,9 @@ def test_slo_measures_hol_inflation_and_feeds_it_back():
                   prompt_mix=((1.0, math.log(6000.0), 0.3),),
                   output_mu=math.log(8.0), output_sigma=0.3,
                   arrival_rate=400.0)
-    r = size_to_slo("homo", wl, H100_LLAMA70B, LLAMA31_70B,
-                    n_requests=1200, seed=0, max_rounds=4, trim=False)
+    spec = TopologySpec.from_kind("homo", H100_LLAMA70B, LLAMA31_70B)
+    r = size_to_slo_spec(spec, wl, n_requests=1200, seed=0, max_rounds=4,
+                         trim=False)
     assert r.measured_hol["homo"] > 1.0
     o = r.overrides["homo"]
     assert o.hol_inflation is not None and 1.0 < o.hol_inflation <= 2.15
@@ -241,9 +250,9 @@ def test_slo_tpot_violations_grow_decode_fleet():
     the decode pools (prefill capacity cannot buy TPOT).  6 ms sits below
     the physical tau floor, so the run is not expected to comply — the
     pin is the *attribution*: decode grows, prefill does not."""
-    r = size_to_slo("disagg", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                    n_requests=1500, seed=0, max_rounds=2,
-                    slo=SLOSpec(ttft_p99_s=0.5, tpot_p99_ms=6.0))
+    spec = TopologySpec.from_kind("disagg", H100_LLAMA70B, LLAMA31_70B)
+    r = size_to_slo_spec(spec, AZURE, n_requests=1500, seed=0, max_rounds=2,
+                         slo=SLOSpec(ttft_p99_s=0.5, tpot_p99_ms=6.0))
     r0, r1 = r.rounds[0].instances, r.rounds[1].instances
     assert r.rounds[0].violators["decode-64K"] > 0
     assert r1["decode-64K"] > r0["decode-64K"]

@@ -154,9 +154,11 @@ def test_batched_fleet_matches_scalar_seed_numbers():
     per-instance fleet numbers recorded before the SoA refactor (Azure
     fleetopt, 1000 requests, seed 0 — the committed --quick baseline
     cell, regenerated by the per-engine implementation)."""
-    from repro.serving import simulate_topology
-    cell = simulate_topology("fleetopt", AZURE, H100_LLAMA70B, LLAMA31_70B,
-                             b_short=4096, n_requests=1000, seed=0)
+    from repro.core.topospec import TopologySpec
+    from repro.serving import simulate_spec
+    spec = TopologySpec.from_kind("fleetopt", H100_LLAMA70B, LLAMA31_70B,
+                                  b_short=4096)
+    cell = simulate_spec(spec, AZURE, n_requests=1000, seed=0)
     f = cell.report["fleet"]
     assert f["completed"] == 1000
     assert round(cell.sim_decode_tok_per_watt, 2) == 5.66

@@ -55,7 +55,7 @@ def test_router_propagates_truncation():
                       streamed_params=STREAMED, window=8192,
                       prefill_chunk=256, respect_arrival=True,
                       name="only")
-    router = ContextRouter({"only": pool}, RouterPolicy(
-        kind="homo", ladder=[("only", math.inf)]))
+    router = ContextRouter({"only": pool},
+                           RouterPolicy(ladder=[("only", math.inf)]))
     with pytest.raises(DrainTruncatedError):
         router.run(_reqs(), max_iters=3)
