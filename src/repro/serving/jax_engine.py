@@ -25,6 +25,15 @@ Layout / padding / masking
     engine's exact per-category append order — onto the live `Request`
     objects and the numpy `MeterBank`, so FleetSim's cross-pool flow
     (overflow / escalation / KV handoff) is byte-identical downstream.
+  * Row lookups (`out[i, m] = v[i, idx[i, m]]`) lower by platform
+    (`_take_rows`): a gather on the CPU, a one-hot select on the TPU.
+    Admission reads the queue only through a K-entry window at each
+    row's head (K = min(S, Q, 128), `_window_len`): the two K-entry
+    blocks that hold it are selected over the Q/K blocks
+    (`_block_pair`), each admitted slot reads its entry from them through
+    `_take_rows`, and admission runs in waves of at most K entries, so no
+    lookup of `admit` spans the (I, S, Q) product.  `emit` still looks
+    each queue entry's slot up over S.
 
 Parity contract: every meter expression replicates `energy.MeterBank`
 operation-for-operation in float64 (`repro.models.compat.enable_x64` is
@@ -85,26 +94,30 @@ def _platform() -> str:
     return jax.default_backend()
 
 
+def _select_reduce(hot, vals, axis):
+    """The value where `hot` along `axis` (one hot lane at most), reduced
+    so that it only selects: `any` for bools, else a max over the value
+    where hot and the dtype's least value elsewhere (a float keeps -0.0,
+    inf and nan).  No hot lane reads that least value (False for bools)."""
+    if vals.dtype == jnp.bool_:
+        return (hot & vals).any(axis)
+    fill = -jnp.inf if jnp.issubdtype(vals.dtype, jnp.floating) \
+        else jnp.iinfo(vals.dtype).min
+    return jnp.where(hot, vals, fill).max(axis)
+
+
 def _select_rows(v, idx):
     """`take_along_axis(v, idx, axis=1)` for (I, N) `v` and (I, M) `idx`
-    in [0, N), as a one-hot over N reduced so that it only selects: `any`
-    for bools, else a max over the value where hot and the dtype's least
-    value elsewhere (a float keeps -0.0, inf and nan).  The longer of N
-    and M is the minor axis of the (I, ., .) one-hot, so TPU lanes stay
-    full when the other is a handful of slots."""
+    in [0, N), as a one-hot over N reduced by `_select_reduce`.  The
+    longer of N and M is the minor axis of the (I, ., .) one-hot, so TPU
+    lanes stay full when the other is a handful of slots."""
     n, m = v.shape[1], idx.shape[1]
     cols = jnp.arange(n, dtype=idx.dtype)
     if m >= n:
-        hot, vals, axis = idx[:, None, :] == cols[None, :, None], \
-            v[:, :, None], 1
-    else:
-        hot, vals, axis = idx[:, :, None] == cols[None, None, :], \
-            v[:, None, :], 2
-    if v.dtype == jnp.bool_:
-        return (hot & vals).any(axis)
-    fill = -jnp.inf if jnp.issubdtype(v.dtype, jnp.floating) \
-        else jnp.iinfo(v.dtype).min
-    return jnp.where(hot, vals, fill).max(axis)
+        return _select_reduce(idx[:, None, :] == cols[None, :, None],
+                              v[:, :, None], 1)
+    return _select_reduce(idx[:, :, None] == cols[None, None, :],
+                          v[:, None, :], 2)
 
 
 def _take_rows(v, idx, platform: str):
@@ -125,6 +138,46 @@ def _rank_rows(cum, ranks, platform: str):
     gathers nothing."""
     method = "compare_all" if _lookup(platform) == "select" else "scan"
     return jax.vmap(partial(jnp.searchsorted, method=method))(cum, ranks)
+
+
+def _window_len(n_slots: int, n_queue: int) -> int:
+    """K, the queue entries `admit` reads at each row's head a wave:
+    min(S, Q, 128), lowered to a divisor of Q (a power of two already for
+    bucketed shapes) so that the queue views as (I, Q/K, K) blocks."""
+    k = min(n_slots, n_queue, 128)
+    while n_queue % k:
+        k -= 1
+    return k
+
+
+def _pair_hot(start, k: int, n_blocks: int):
+    """(I, 2, Q/K): blocks start // K and start // K + 1 of each row, one
+    hot over the row's Q/K blocks of K entries (none past the last)."""
+    blk = (start // k)[:, None] + jnp.arange(2, dtype=start.dtype)
+    return blk[:, :, None] == jnp.arange(n_blocks, dtype=start.dtype)
+
+
+def _block_pair(v, start, k: int):
+    """(..., I, 2K): the K-entry blocks start // K and start // K + 1 of
+    each row of a (..., I, Q) `v`, read with a one-hot over its Q/K blocks
+    (2·I·Q lanes a column, a block's K entries lane-minor), so lane c
+    holds entry start + c - start % K.  A block past Q reads the fill of
+    `_select_reduce`."""
+    *lead, I, Q = v.shape
+    hot = _pair_hot(start, k, Q // k)[..., None]
+    return _select_reduce(hot, v.reshape(*lead, I, 1, Q // k, k),
+                          -2).reshape(*lead, I, 2 * k)
+
+
+def _put_pair(v, start, vals, put, k: int):
+    """(I, Q) `v` with the lanes of its block pair at `start` (as
+    `_block_pair` reads them) set to (I, 2K) `vals` where `put`: a select
+    over the pair's two blocks, with no scatter."""
+    I, Q = v.shape
+    hot = _pair_hot(start, k, Q // k)[..., None] \
+        & put.reshape(I, 2, 1, k)                        # (I, 2, Q/K, K)
+    new = _select_reduce(hot, vals.reshape(I, 2, 1, k), 1)
+    return jnp.where(hot.any(1), new, v.reshape(I, Q // k, k)).reshape(I, Q)
 
 
 def _bucket(n: int, floor: int = 8) -> int:
@@ -176,7 +229,7 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
 
     st0 = dict(
         sim_time=zero_f(I), qpos=zero_i(I), it=jnp.asarray(0, i32),
-        slot_iters=jnp.asarray(0, i32),
+        slot_iters=jnp.asarray(0, i32), admit_waves=jnp.asarray(0, i32),
         active=jnp.zeros((I, S), bool),
         pos=zero_i(I, S), gen_count=zero_i(I, S), m_gen=zero_i(I, S),
         max_new=zero_i(I, S), prefill_left=zero_i(I, S),
@@ -265,48 +318,87 @@ def _drain_one(p: Dict[str, "jax.Array"], *, phase: str, n_slots_pad: int,
         st["prefill_tokens"] += take.sum(1, dtype=jnp.int32)
         return st, sim + dt.sum(1), t_before + dt
 
+    # admission reads the queue through a K-entry window at each row's
+    # head, in waves of at most K entries; n_admit <= min(S, Q) entries
+    # always fit one wave where min(S, Q) <= K.  The int32 columns it
+    # reads are stacked once, so each step of a read is one op for all.
+    K = _window_len(S, Q)
+    q_cols = jnp.stack([p["q_plen"], p["q_maxnew"], p["q_esc"],
+                        p["q_pdone"].astype(i32)])
+    lanes = jnp.arange(2 * K, dtype=i32)[None, :]
+
+    def admit_wave(st, n_w):
+        """Admit each row's next n_w (<= K, <= its free slots) queue
+        entries into its n_w lowest free slots: the j-th entry from `qpos`
+        lands in the j-th lowest free slot.  The window [qpos, qpos + K)
+        lies in the block pair at `qpos` (`_block_pair`); each admitted
+        slot reads the pair's lane qpos % K + its free rank, so a wave
+        costs I·S·2K lanes a column where a lookup over the whole queue
+        cost I·S·Q."""
+        qpos = st["qpos"]
+        free = (~st["active"]) & slot_ok
+        cum_free = jnp.cumsum(free, axis=1, dtype=i32)
+        free_rank = cum_free - free
+        adm = free & (free_rank < n_w[:, None])
+        shift = (qpos % K)[:, None]
+        at = shift + jnp.clip(free_rank, 0, K - 1)
+        a_plen, a_maxnew, a_esc, a_pd = jax.vmap(
+            take_rows, in_axes=(0, None))(_block_pair(q_cols, qpos, K), at)
+        a_ready = take_rows(_block_pair(p["q_ready"], qpos, K), at)
+        # inverse mapping for `emit`: the window's j-th entry (pair lane
+        # shift + j) lands in the first slot s with cum_free[s] == j+1
+        j = lanes - shift
+        slot_of = _rank_rows(cum_free, j + 1, platform)
+        st["q_slot"] = _put_pair(
+            st["q_slot"], qpos, jnp.clip(slot_of, 0, S - 1).astype(i32),
+            (j >= 0) & (j < n_w[:, None]), K)
+        st["active"] = st["active"] | adm
+        st["pos"] = jnp.where(adm, a_plen, st["pos"])
+        st["max_new"] = jnp.where(adm, a_maxnew, st["max_new"])
+        st["ready_ts"] = jnp.where(adm, a_ready, st["ready_ts"])
+        st["esc"] = jnp.where(adm, a_esc, st["esc"])
+        st["slot_q"] = jnp.where(adm, qpos[:, None] + free_rank, st["slot_q"])
+        st["gen_count"] = jnp.where(adm, jnp.where(a_pd != 0, 1, 0),
+                                    st["gen_count"])
+        st["prefill_left"] = jnp.where(adm, jnp.where(a_pd != 0, 0, a_plen),
+                                       st["prefill_left"])
+        st["m_gen"] = jnp.where(adm, 0, st["m_gen"])
+        st["qpos"] = qpos + n_w
+        st["admit_waves"] = st["admit_waves"] + n_w.any().astype(i32)
+        return st
+
     @jax.named_scope("admit")
     def admit(st, sim):
         """Head-gated FIFO admission of the ready queue prefix into the
         lowest free slots (chunked mode never advances the clock here, so
-        the whole wave vectorizes: the j-th admitted entry lands in the
-        j-th lowest inactive slot)."""
+        a wave vectorizes), in waves of at most K entries: a wave fills the
+        lowest free slots, so the next wave's j-th lowest free slot is the
+        whole admission's (K + j)-th and waves admit what one pass would.
+        Where S and Q both exceed K a `while_loop` runs a wave while a row
+        has entries left: none in an iteration that admits nothing, more
+        than one only in a burst."""
         rem = (qidx >= st["qpos"][:, None]) & (qidx < p["qlen"][:, None])
         # respect=False degenerates to "whole queue is ready now"
         notready = rem & (p["q_ready"] > sim[:, None]) & respect[:, None]
         first_nr = jnp.argmax(notready, axis=1).astype(i32)
         prefix_end = jnp.where(notready.any(1), first_nr, p["qlen"])
         n_ready = jnp.maximum(prefix_end - st["qpos"], 0)
-        free = (~st["active"]) & slot_ok
-        cum_free = jnp.cumsum(free, axis=1, dtype=i32)
-        n_admit = jnp.minimum(n_ready, cum_free[:, -1])
-        free_rank = cum_free - free
-        adm = free & (free_rank < n_admit[:, None])
-        src = jnp.clip(st["qpos"][:, None] + free_rank, 0, Q - 1)
-        # inverse mapping for `emit`: the j-th admitted queue entry lands
-        # in the j-th lowest free slot = first s with cum_free[s] == j+1
-        adm_q = (qidx >= st["qpos"][:, None]) \
-            & (qidx < (st["qpos"] + n_admit)[:, None])
-        ranks = qidx - st["qpos"][:, None] + 1
-        slot_of_q = _rank_rows(cum_free, ranks, platform).astype(i32)
-        st["q_slot"] = jnp.where(adm_q, jnp.clip(slot_of_q, 0, S - 1),
-                                 st["q_slot"])
-        at_src = lambda a: take_rows(a, src)  # noqa: E731
-        a_plen = at_src(p["q_plen"])
-        a_pd = at_src(p["q_pdone"])
-        st["active"] = st["active"] | adm
-        st["pos"] = jnp.where(adm, a_plen, st["pos"])
-        st["max_new"] = jnp.where(adm, at_src(p["q_maxnew"]), st["max_new"])
-        st["ready_ts"] = jnp.where(adm, at_src(p["q_ready"]), st["ready_ts"])
-        st["esc"] = jnp.where(adm, at_src(p["q_esc"]), st["esc"])
-        st["slot_q"] = jnp.where(adm, src, st["slot_q"])
-        st["gen_count"] = jnp.where(adm, jnp.where(a_pd, 1, 0),
-                                    st["gen_count"])
-        st["prefill_left"] = jnp.where(adm, jnp.where(a_pd, 0, a_plen),
-                                       st["prefill_left"])
-        st["m_gen"] = jnp.where(adm, 0, st["m_gen"])
-        st["qpos"] = st["qpos"] + n_admit
-        return st
+        n_free = ((~st["active"]) & slot_ok).sum(1, dtype=i32)
+        n_admit = jnp.minimum(n_ready, n_free)
+        if min(S, Q) <= K:
+            return admit_wave(st, n_admit)
+        keys = ("active", "pos", "max_new", "ready_ts", "esc", "slot_q",
+                "gen_count", "prefill_left", "m_gen", "q_slot", "qpos",
+                "admit_waves")
+
+        def wave(c):
+            sub, n_left = c
+            n_w = jnp.minimum(n_left, K)
+            return admit_wave(dict(sub), n_w), n_left - n_w
+
+        sub, _ = jax.lax.while_loop(lambda c: c[1].any(), wave,
+                                    ({k: st[k] for k in keys}, n_admit))
+        return {**st, **sub}
 
     @jax.named_scope("decode_step")
     def decode_step(st, sim):
@@ -637,6 +729,9 @@ def drain_engines(engines: Sequence["JaxPoolEngine"], *,
                            it * sum(int(r["qlen"].sum()) for r in rows))
                 host_count("drain.entry_iters_padded", it * i_pad * q_pad)
                 host_count("drain.slot_iters", int(out["slot_iters"]))
+                # admission waves: at most one an iteration unless a burst
+                # overran the K-entry window
+                host_count("drain.admit_waves", int(out["admit_waves"]))
                 host_count("drain.slot_iters_padded", it * i_pad * s_pad)
                 with host_span("drain.split"):
                     _split(out, engs, rows)
@@ -679,7 +774,7 @@ def _split(out: Dict[str, np.ndarray], engs: Sequence["JaxPoolEngine"],
         Q = packed["q_ready"].shape[1]
         res = {}
         for k, v in out.items():
-            if v.ndim == 0:     # the group's `it` and `slot_iters`
+            if v.ndim == 0:     # the group's `it` and loop counters
                 res[k] = v
                 continue
             s = v[off:off + I]

@@ -185,6 +185,40 @@ def test_slot_counters_measure_the_padded_slot_axis(traced):
                   if s[0] == "drain.group") == sorted(slots)
 
 
+def test_admit_wave_counter_sums_what_the_drain_returned(traced):
+    """`drain.admit_waves` is the admission waves the loop ran, summed
+    over groups as the drain returned them.  Every group of the grid has
+    min(S_pad, Q_pad) <= K, so each admitting iteration admits in one
+    wave and the count stays within the group's iterations."""
+    rec, _, finals = traced
+    groups = {}
+    for eng, res in finals:
+        groups.setdefault(id(res["it"]), (res, []))[1].append(eng)
+    waves = 0
+    for res, engs in groups.values():
+        s_pad = _bucket(engs[0].n_slots)
+        q_pad = _bucket(max(1, max(int(e.qlen.max()) for e in engs)))
+        assert min(s_pad, q_pad) <= jax_engine._window_len(s_pad, q_pad)
+        assert 0 < int(res["admit_waves"]) <= int(res["it"])
+        waves += int(res["admit_waves"])
+    assert rec.counters["drain.admit_waves"] == waves
+
+
+def test_admit_wave_counter_reads_zero_for_an_empty_group():
+    """A group of empty pools runs no iteration and admits in no wave;
+    the counter is recorded all the same."""
+    eng = JaxPoolEngine(instances=3, window=4096, n_slots=4,
+                        profile=H100_LLAMA70B, prefill_chunk=256,
+                        streamed_params=LLAMA31_70B.streamed_params,
+                        respect_arrival=True)
+    eng.sort_queues()
+    with telemetry.host_tracing() as rec:
+        drain_engines([eng])
+    assert rec.counters["drain.groups"] == 1
+    assert rec.counters["drain.iters"] == 0
+    assert rec.counters["drain.admit_waves"] == 0
+
+
 def test_grid_reports_are_bit_identical_with_the_recorder_off(traced):
     _, reports_on, _ = traced
     assert _grid() == reports_on

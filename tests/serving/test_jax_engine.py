@@ -203,13 +203,29 @@ def _case_wide_spill():
                                     prefill_chunk=512, phase="prefill"))]
 
 
+def _case_burst():
+    """More requests arrive at once than the admission window holds: 300
+    slots (S pads to 512, Q to 1024, so K = 128) filled from a standing
+    queue, whole in the decode phase, where prefilled requests all finish
+    together, and in the prefill phase as prompts of mixed length drain."""
+    rng = np.random.default_rng(31)
+    decode = [[_req(i + 1000 * j, 64, 20, pdone=True) for i in range(n)]
+              for j, n in enumerate((600, 450))]
+    prefill = [[_req(5000 + i, int(rng.integers(16, 200)), 1)
+                for i in range(700)]]
+    return [(decode, dict(window=4096, n_slots=300, prefill_chunk=512)),
+            (prefill, dict(window=4096, n_slots=300, prefill_chunk=512,
+                           phase="prefill"))]
+
+
 CASES = {"interleave": _case_interleave,
          "overflow_chain": _case_overflow_chain,
          "escalation_in_window": _case_escalation_in_window,
          "prefill_fifo": _case_prefill_fifo,
          "ragged": _case_ragged,
          "hybrid": _case_hybrid,
-         "wide_spill": _case_wide_spill}
+         "wide_spill": _case_wide_spill,
+         "burst": _case_burst}
 
 
 def test_jax_parity_admission_and_chunked_interleave():
@@ -332,6 +348,67 @@ def test_wide_spill_case_charges_three_slots_a_step():
     for i in range(out["out_kind"].shape[0]):
         per_step = np.bincount(out["out_step"][i][handoff[i]])
         assert per_step.max() >= 3, per_step
+
+
+@pytest.mark.parametrize("platform", ["cpu", "tpu"])
+def test_burst_admits_in_several_waves(platform, monkeypatch):
+    """A burst wider than the K-entry admission window admits in several
+    waves of one loop iteration, under either lowering, and still matches
+    the numpy oracle.  A slot frees only at an event, so at most one
+    iteration more than the distinct event steps admits anything; the
+    decode pool's two rows admit 300 entries twice (150 once), each time
+    in ceil(300 / 128) = 3 waves."""
+    monkeypatch.setattr(jax_engine, "_platform", lambda: platform)
+    refs, jxs, staged = _drain_case(_case_burst())
+    for ref, jx in zip(refs, jxs):
+        _assert_parity(ref, jx)
+    out = staged[0]
+    assert jax_engine._window_len(512, 1024) == 128
+    admitting = 1 + len(np.unique(out["out_step"][out["out_kind"] > 0]))
+    assert admitting == 3
+    assert int(out["admit_waves"]) == 6 > admitting
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_admit_reads_no_whole_queue_a_slot(phase):
+    """At the hybrid binding's padded shape (I=8, S=1024, Q=2048) on the
+    TPU's lowering, no equation of `admit`, its wave loop's included, has
+    an operand or output of I·S·Q elements: the queue is read through a
+    K-entry window at each row's head, not looked up over the whole Q
+    axis at every slot."""
+    I, S, Q = 8, 1024, 2048
+    eng = JaxPoolEngine(instances=I, window=8192, n_slots=S, profile=HYBRID,
+                        streamed_params=STREAMED, prefill_chunk=512,
+                        respect_arrival=True, phase=phase)
+    with enable_x64():
+        args = {k: jax.ShapeDtypeStruct(
+                    (I, Q) if np.ndim(a) == 2 else np.shape(a),
+                    np.asarray(a).dtype)
+                for k, a in eng._pack(max_iters=1000).items()}
+        closed = jax.make_jaxpr(partial(
+            jax_engine._drain_one, phase=phase, n_slots_pad=S,
+            platform="tpu"))(args)
+
+    def subjaxprs(params):
+        for v in params.values():
+            for x in (v if isinstance(v, (tuple, list)) else (v,)):
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    yield x
+
+    def walk(jaxpr, scoped):
+        for eqn in jaxpr.eqns:
+            inner = scoped or "admit" in str(eqn.source_info.name_stack)
+            if inner:
+                yield eqn
+            for sub in subjaxprs(eqn.params):
+                yield from walk(sub, inner)
+
+    admit = list(walk(closed.jaxpr, False))
+    assert any(e.primitive.name == "while" for e in admit)  # the waves
+    sizes = {int(np.prod(getattr(v.aval, "shape", ())))
+             for e in admit for v in (*e.invars, *e.outvars)}
+    assert I * Q in sizes and I * S * Q not in sizes
 
 
 # --- the lowered drain's prefix scans ------------------------------------
